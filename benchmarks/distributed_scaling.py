@@ -1,6 +1,7 @@
 """Distributed-scan scaling: ``method="distributed"`` vs single-device.
 
-Forces host-platform devices (CPU) and times the SAME MAP problem solved
+A standalone CPU rehearsal, not a chip benchmark: it forces host-platform
+devices (CPU) and times the SAME MAP problem solved
 through the public Estimator surface at increasing time-shard counts P:
 
 * **strong scaling** -- total block count T fixed, P grows: per-solve
@@ -15,17 +16,11 @@ through the public Estimator surface at increasing time-shard counts P:
 distributed method's fallback, so each sweep carries its own baseline.
 
     PYTHONPATH=src python benchmarks/distributed_scaling.py [--smoke] \\
-        [--json PATH] [--emit-rows]
-
-``--emit-rows`` prints one JSON object per row (for ``benchmarks/run.py``,
-which runs this script as a subprocess: the parent's jax is already
-initialised with the real device count, and XLA's forced host-device
-count locks at first init).
+        [--json PATH]
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 import time
@@ -103,20 +98,14 @@ def main() -> None:
                     help="tiny problem sizes (CI bit-rot check)")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="write a BENCH json artifact for this section")
-    ap.add_argument("--emit-rows", action="store_true",
-                    help="print one JSON row per line (run.py subprocess)")
     args = ap.parse_args()
     import repro.obs as obs
     if args.json:
         obs.enable()
         obs.reset()
     rows = run(smoke=args.smoke)
-    if args.emit_rows:
-        for r in rows:
-            print(json.dumps(r))
-    else:
-        for r in rows:
-            print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
+    for r in rows:
+        print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
     if args.json:
         obs.write_bench_json(
             args.json, obs.bench_record("dist", rows, seeds={"dist": 0}))

@@ -19,8 +19,11 @@ committed ``benchmarks/baselines/BENCH_seed.json`` with
   stream/*  StreamingEngine window latency + tracks/sec: fixed-lag
             in-order, 10% late pushes through the reorder-slack path
             (merge/drop accounting), and adaptive-lag self-tuning
-  dist/*    method="distributed" weak/strong scaling (subprocess with
-            forced host devices -- this process's device count is locked)
+
+Every row names the device it ran on (platform, device kind, device
+count).  The time-sharded scaling sweep is not a section here: it needs
+several devices, and ``benchmarks/distributed_scaling.py`` rehearses it
+standalone on forced CPU host devices.
 
 ``--fast`` shrinks the sweeps (CI-sized); ``--smoke`` shrinks further to
 bit-rot-check sizes (every section runs in seconds); default runs the full
@@ -37,30 +40,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 # fixed RNG seeds per section -- recorded into the JSON artifact so every
 # number is reproducible from the file alone
 SEEDS = {"fig1": 0, "fig2": 1, "nonlin": 3, "kern": 0, "batch": 0,
-         "serve": 0, "stream": 0, "dist": 0}
-
-
-def _dist_rows(smoke: bool) -> list:
-    """Run benchmarks/distributed_scaling.py in a subprocess (XLA's forced
-    host-device count locks at first jax init, so the 8-device sweep
-    cannot run in this process) and parse its --emit-rows output."""
-    import json
-    import os
-    import subprocess
-
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env.setdefault("REPRO_BENCH_DEVICES", "8")
-    cmd = [sys.executable,
-           str(Path(__file__).resolve().parent / "distributed_scaling.py"),
-           "--emit-rows"] + (["--smoke"] if smoke else [])
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"distributed_scaling subprocess failed:\n{out.stderr[-4000:]}")
-    return [json.loads(line) for line in out.stdout.splitlines()
-            if line.strip().startswith("{")]
+         "serve": 0, "stream": 0}
 
 
 def main() -> None:
@@ -70,14 +50,19 @@ def main() -> None:
                     help="tiny sizes: CI bit-rot check for every section")
     ap.add_argument("--only", default="",
                     help="comma list: fig1,fig2,nonlin,kern,batch,serve,"
-                         "stream,dist")
+                         "stream")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="write the BENCH_<name>.json artifact here "
                          "(CI: BENCH_smoke.json)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    import jax
+
     import repro.obs as obs
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     obs.enable()
     obs.reset()
 
@@ -116,10 +101,12 @@ def main() -> None:
         rows += engine_latency.run(smoke=args.smoke or args.fast)
     if only is None or "stream" in only:
         rows += streaming_latency.run(smoke=args.smoke or args.fast)
-    if only is None or "dist" in only:
-        rows += _dist_rows(smoke=args.smoke or args.fast)
 
+    dev = jax.devices()[0]
+    device = (f"platform={dev.platform},device_kind={dev.device_kind},"
+              f"device_count={len(jax.devices())}")
     for r in rows:
+        r["derived"] = f"{r['derived']},{device}" if r["derived"] else device
         print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
 
     if args.json:

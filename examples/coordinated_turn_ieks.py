@@ -15,6 +15,10 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 import jax.numpy as jnp
 
 from repro.configs.coordinated_turn import CoordinatedTurnConfig

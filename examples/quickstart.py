@@ -11,6 +11,10 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 import jax.numpy as jnp
 
 from repro.configs.wiener_velocity import WienerVelocityConfig

@@ -1,9 +1,9 @@
 """Associative combination operators (paper eqs. 42, 45-46, and the
 value-application step used for within-block interior fills).
 
-All operators broadcast over arbitrary leading batch axes: ``A @ B`` and
-``jnp.linalg.solve`` batch over leading dimensions, so the same code path is
-used for single pairs, vmapped blocks, and the Pallas kernel oracle
+All operators broadcast over arbitrary leading batch axes: the products and
+solves of :mod:`repro.core.linalg` batch over leading dimensions, so the same
+code path is used for single pairs, vmapped blocks, and the Pallas kernel oracle
 (``repro.kernels.lqt_combine.ref`` re-exports :func:`lqt_combine`).
 
 Orientation convention: ``combine(e1, e2)`` composes ``e1`` on the EARLIER
@@ -14,12 +14,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from . import linalg
+from .linalg import mT, mm, mv
 from .types import AffineElement, LQTElement, ValueFn
 
 
 def _sym(M: jnp.ndarray) -> jnp.ndarray:
     """Numerically symmetrise a (batched) matrix."""
-    return 0.5 * (M + jnp.swapaxes(M, -1, -2))
+    return 0.5 * (M + mT(M))
 
 
 def _eye_like(M: jnp.ndarray) -> jnp.ndarray:
@@ -38,14 +40,14 @@ def lqt_combine(e1: LQTElement, e2: LQTElement) -> LQTElement:
     A2, b2, C2, eta2, J2 = e2
 
     I = _eye_like(C1)
-    M = I + C1 @ J2                      # (..., nx, nx)
-    Mt = jnp.swapaxes(M, -1, -2)         # = I + J2 C1
+    M = I + mm(C1, J2)                   # (..., nx, nx)
+    Mt = mT(M)                           # = I + J2 C1
 
     # Right-hand sides solved against M:   M^{-1} [A1 | b1 + C1 eta2 | C1]
     rhs1 = jnp.concatenate(
-        [A1, (b1 + (C1 @ eta2[..., None])[..., 0])[..., None], C1], axis=-1
+        [A1, (b1 + mv(C1, eta2))[..., None], C1], axis=-1
     )
-    sol1 = jnp.linalg.solve(M, rhs1)
+    sol1 = linalg.solve(M, rhs1)
     nx = A1.shape[-1]
     MiA1 = sol1[..., :nx]
     Mib = sol1[..., nx]
@@ -53,18 +55,18 @@ def lqt_combine(e1: LQTElement, e2: LQTElement) -> LQTElement:
 
     # Solved against M^T:   (I + J2 C1)^{-1} [eta2 - J2 b1 | J2 A1]
     rhs2 = jnp.concatenate(
-        [(eta2 - (J2 @ b1[..., None])[..., 0])[..., None], J2 @ A1], axis=-1
+        [(eta2 - mv(J2, b1))[..., None], mm(J2, A1)], axis=-1
     )
-    sol2 = jnp.linalg.solve(Mt, rhs2)
+    sol2 = linalg.solve(Mt, rhs2)
     Mte = sol2[..., 0]
     MtJA = sol2[..., 1:]
 
-    A1T = jnp.swapaxes(A1, -1, -2)
-    A = A2 @ MiA1
-    b = (A2 @ Mib[..., None])[..., 0] + b2
-    C = _sym(A2 @ MiC1 @ jnp.swapaxes(A2, -1, -2) + C2)
-    eta = (A1T @ Mte[..., None])[..., 0] + eta1
-    J = _sym(A1T @ MtJA + J1)
+    A1T = mT(A1)
+    A = mm(A2, MiA1)
+    b = mv(A2, Mib) + b2
+    C = _sym(mm(mm(A2, MiC1), mT(A2)) + C2)
+    eta = mv(A1T, Mte) + eta1
+    J = _sym(mm(A1T, MtJA) + J1)
     return LQTElement(A, b, C, eta, J)
 
 
@@ -73,8 +75,8 @@ def affine_combine(e1: AffineElement, e2: AffineElement) -> AffineElement:
 
     ``e1`` maps over the earlier interval, ``e2`` over the later one.
     """
-    Phi = e2.Phi @ e1.Phi
-    beta = (e2.Phi @ e1.beta[..., None])[..., 0] + e2.beta
+    Phi = mm(e2.Phi, e1.Phi)
+    beta = mv(e2.Phi, e1.beta) + e2.beta
     return AffineElement(Phi, beta)
 
 
@@ -93,14 +95,14 @@ def apply_element_to_value(e: LQTElement, vf: ValueFn) -> ValueFn:
     A, b, C, eta, J = e
     S2, v2 = vf
     I = _eye_like(C)
-    Mt = I + S2 @ C  # (I + J2 C1) with J2 = S2, C1 = C
+    Mt = I + mm(S2, C)  # (I + J2 C1) with J2 = S2, C1 = C
     rhs = jnp.concatenate(
-        [(v2 - (S2 @ b[..., None])[..., 0])[..., None], S2 @ A], axis=-1
+        [(v2 - mv(S2, b))[..., None], mm(S2, A)], axis=-1
     )
-    sol = jnp.linalg.solve(Mt, rhs)
-    At = jnp.swapaxes(A, -1, -2)
-    v = (At @ sol[..., 0][..., None])[..., 0] + eta
-    S = _sym(At @ sol[..., 1:] + J)
+    sol = linalg.solve(Mt, rhs)
+    At = mT(A)
+    v = mv(At, sol[..., 0]) + eta
+    S = _sym(mm(At, sol[..., 1:]) + J)
     return ValueFn(S, v)
 
 
@@ -132,10 +134,10 @@ def elem_min_initial(e0: LQTElement, jitter: float = 0.0) -> LQTElement:
     if jitter:
         scale = jnp.trace(J0) / nx
         J0 = J0 + (jitter * scale) * I
-    sol = jnp.linalg.solve(J0, jnp.concatenate([eta0[..., None], jnp.swapaxes(A0, -1, -2)], axis=-1))
+    sol = linalg.solve(J0, jnp.concatenate([eta0[..., None], mT(A0)], axis=-1))
     J0ie = sol[..., 0]
     J0iA0T = sol[..., 1:]
     Abar = jnp.zeros_like(A0)
-    bbar = b0 + (A0 @ J0ie[..., None])[..., 0]
-    Cbar = _sym(A0 @ J0iA0T + C0)
+    bbar = b0 + mv(A0, J0ie)
+    Cbar = _sym(mm(A0, J0iA0T) + C0)
     return LQTElement(Abar, bbar, Cbar, eta0, J0)

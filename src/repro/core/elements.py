@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from .combine import apply_element_to_value, lqt_combine
+from .linalg import mT, mm, mv
 from .types import GridLQT, LQTElement, ValueFn
 
 
@@ -55,13 +56,13 @@ def one_step_elements(grid: GridLQT) -> LQTElement:
     """Closed-form single-substep elements (N, ...) -- ``discrete`` mode."""
     dt = grid.dt[:, None, None]
     I = jnp.eye(grid.nx, dtype=grid.F.dtype)
-    HtRi = jnp.einsum("kji,kjl->kil", grid.H, grid.Rinv)
+    HtRi = mm(mT(grid.H), grid.Rinv)
     A = I + dt * grid.F
     b = grid.dt[:, None] * grid.c
     C = dt * grid.Q
-    J = dt * (HtRi @ grid.H)
+    J = dt * mm(HtRi, grid.H)
     eta = grid.dt[:, None] * (
-        jnp.einsum("kij,kj->ki", HtRi, grid.y - grid.r) - _lin_term(grid))
+        mv(HtRi, grid.y - grid.r) - _lin_term(grid))
     return LQTElement(A, b, C, eta, J)
 
 
@@ -75,13 +76,13 @@ def terminal_element(grid: GridLQT) -> LQTElement:
 def _hjb_derivs(e: LQTElement, F, c, H, r, Q, Rinv, y, lin):
     """Right-hand sides of eq. (43) (with the optional linear-cost term)."""
     A, b, C, eta, J = e
-    HtRi = H.T @ Rinv
-    innov = HtRi @ (y - r)
-    dA = -A @ (Q @ J + F)
-    db = -A @ (Q @ eta + c)
-    dC = -A @ Q @ A.T
-    deta = J @ (Q @ eta + c) - F.T @ eta - innov + lin
-    dJ = J @ Q @ J - J @ F - F.T @ J - HtRi @ H
+    HtRi = mm(mT(H), Rinv)
+    innov = mv(HtRi, y - r)
+    dA = -mm(A, mm(Q, J) + F)
+    db = -mv(A, mv(Q, eta) + c)
+    dC = -mm(mm(A, Q), mT(A))
+    deta = mv(J, mv(Q, eta) + c) - mv(mT(F), eta) - innov + lin
+    dJ = mm(mm(J, Q), J) - mm(J, F) - mm(mT(F), J) - mm(HtRi, H)
     return LQTElement(dA, db, dC, deta, dJ)
 
 
@@ -188,16 +189,18 @@ def backward_value_fill_euler(grid: GridLQT, nsub: int, boundary: ValueFn,
     def block(dt, F, c, H, r, Q, Rinv, y, linb, S1, v1):
         def step(carry, inp):
             dtk, Fk, ck, Hk, rk, Qk, Rik, yk, lk = inp
-            HtRi = Hk.T @ Rik
+            HtRi = mm(mT(Hk), Rik)
 
             def derivs(sv):
                 S, v = sv
-                dS = S @ Qk @ S - S @ Fk - Fk.T @ S - HtRi @ Hk
-                dv = S @ (Qk @ v + ck) - Fk.T @ v - HtRi @ (yk - rk) + lk
+                dS = (mm(mm(S, Qk), S) - mm(S, Fk) - mm(mT(Fk), S)
+                      - mm(HtRi, Hk))
+                dv = (mv(S, mv(Qk, v) + ck) - mv(mT(Fk), v)
+                      - mv(HtRi, yk - rk) + lk)
                 return (dS, dv)
 
             Sn, vn = _ode_step_backward(derivs, carry, dtk, integrator)
-            Sn = 0.5 * (Sn + Sn.T)
+            Sn = 0.5 * (Sn + mT(Sn))
             return (Sn, vn), (Sn, vn)
 
         _, (Ss, vs) = jax.lax.scan(
@@ -242,20 +245,20 @@ def forward_value_fill_euler(
         def step(carry, inp):
             A, b, C, eta, J = carry
             dtk, Fk, ck, Hk, rk, Qk, Rik, yk, lk = inp
-            HtRi = Hk.T @ Rik
-            CHtRi = C @ HtRi
-            innov = HtRi @ (yk - rk)
-            dA = -CHtRi @ (Hk @ A) + Fk @ A
-            db = (C @ innov + Fk @ b + ck
-                  - CHtRi @ (Hk @ b) - C @ lk)
-            dC = -CHtRi @ (Hk @ C) + Qk + Fk @ C + C @ Fk.T
-            deta = A.T @ (innov - HtRi @ (Hk @ b) - lk)
-            dJ = A.T @ HtRi @ (Hk @ A)
+            HtRi = mm(mT(Hk), Rik)
+            CHtRi = mm(C, HtRi)
+            innov = mv(HtRi, yk - rk)
+            dA = -mm(CHtRi, mm(Hk, A)) + mm(Fk, A)
+            db = (mv(C, innov) + mv(Fk, b) + ck
+                  - mv(CHtRi, mv(Hk, b)) - mv(C, lk))
+            dC = -mm(CHtRi, mm(Hk, C)) + Qk + mm(Fk, C) + mm(C, mT(Fk))
+            deta = mv(mT(A), innov - mv(HtRi, mv(Hk, b)) - lk)
+            dJ = mm(mm(mT(A), HtRi), mm(Hk, A))
             An = A + dtk * dA
             bn = b + dtk * db
-            Cn = 0.5 * ((C + dtk * dC) + (C + dtk * dC).T)
+            Cn = 0.5 * ((C + dtk * dC) + mT(C + dtk * dC))
             en = eta + dtk * deta
-            Jn = 0.5 * ((J + dtk * dJ) + (J + dtk * dJ).T)
+            Jn = 0.5 * ((J + dtk * dJ) + mT(J + dtk * dJ))
             nxt = LQTElement(An, bn, Cn, en, Jn)
             return nxt, nxt
 

@@ -416,8 +416,19 @@ def _solve_arrays(model: Model, spec: MethodSpec, options, ts, y, mask,
     grid = grid_lqt_from_linear(model, ts, y, measurement_mask=mask,
                                 prior=prior)
     sol = spec.solver(grid, options)
-    return Solution(x=sol.x, S=sol.S, v=sol.v, cov=sol.cov,
-                    cost=om_cost_grid(grid, sol.x) if diagnostics else None)
+    cost = None
+    if diagnostics:
+        # In float32 (the chip) a time-invariant Q gets one pseudo-inverse,
+        # taken on the host: a per-point SVD compiles for minutes on the
+        # TPU at long horizons.  Float64 (the CPU tests) keeps the
+        # per-point form: with the constant, XLA:CPU 0.9.0's
+        # concurrency-optimized scheduler returned NaN trajectories from
+        # parallel_two_filter.
+        Qpinv = None
+        if not callable(model.Q) and sol.x.dtype != jnp.float64:
+            Qpinv = np.linalg.pinv(np.asarray(model.Q)).astype(sol.x.dtype)
+        cost = om_cost_grid(grid, sol.x, Qpinv)
+    return Solution(x=sol.x, S=sol.S, v=sol.v, cov=sol.cov, cost=cost)
 
 
 def legacy_options(model: Model, method: str, *, nsub=None, mode=None,

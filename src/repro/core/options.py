@@ -14,7 +14,7 @@ universal keyword soup on every entry point:
   :func:`repro.core.parallel.parallel_two_filter`;
 * :class:`KernelOptions` -- parallel options + the Pallas-kernel knobs of
   the ``parallel_kernel`` method (``block_size`` lanes per kernel grid
-  step, ``interpret`` tri-state with automatic non-TPU fallback,
+  step, ``interpret`` tri-state resolved from the backend,
   ``precision`` compute dtype of the kernel scan);
 * :class:`DistributedOptions` -- parallel options + the time-axis-sharding
   knobs of the ``distributed`` method (``time_axis`` / ``batch_axes`` mesh
@@ -103,8 +103,10 @@ class KernelOptions(ParallelOptions):
     ``block_size`` is the lane count per Pallas grid step of the combine
     kernel (128-multiples feed full TPU VREG rows; the wrapper shrinks it
     automatically for small scans).  ``interpret=None`` resolves at solve
-    time to ``True`` off-TPU (Pallas interpreter, bit-accurate semantics)
-    and ``False`` on TPU (Mosaic); pass an explicit bool to force either.
+    time to ``False`` on a TPU (Mosaic) and ``True`` on the CPU (Pallas
+    interpreter, for tests); any other backend raises, so a run never
+    drops to the interpreter unnoticed.  Pass an explicit bool to force
+    either.
     ``precision`` is the kernel compute dtype: ``"default"`` keeps the
     element dtype, ``"float32"``/``"float64"`` cast the lane-major scan
     (TPUs have no native f64 -- use ``"float32"`` there for x64 grids).
@@ -130,14 +132,22 @@ class KernelOptions(ParallelOptions):
                 f"got {self.precision!r}")
 
     def resolve_interpret(self) -> bool:
-        """The effective interpret flag: explicit bool wins; ``None`` means
-        interpret everywhere except a real TPU backend (Mosaic compilation
-        needs one)."""
+        """The effective interpret flag: an explicit bool wins; ``None``
+        means Mosaic on a TPU backend and the interpreter on the CPU
+        backend.  Any other backend raises ``RuntimeError``."""
         if self.interpret is not None:
             return self.interpret
         import jax
 
-        return jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend == "tpu":
+            return False
+        if backend == "cpu":
+            return True
+        raise RuntimeError(
+            f"KernelOptions(interpret=None) has no kernel for backend "
+            f"{backend!r}: the Pallas kernel compiles only for a TPU; pass "
+            f"interpret=True to run it in the interpreter")
 
 
 CARRY_DTYPES = ("default", "float32", "float64")

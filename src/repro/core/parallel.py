@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from . import pscan
 from .combine import affine_combine, elem_min_initial, lqt_combine
+from .linalg import mv
 from .elements import (
     backward_value_fill_discrete,
     backward_value_fill_euler,
@@ -140,11 +141,11 @@ def _recover_affine(grid: GridLQT, values_full: ValueFn, nsub: int,
         prefix = pscan.prefix_scan(affine_combine, totals)    # (T, ...)
 
     phi0 = jnp.linalg.solve(values_full.S[0], values_full.v[0])
-    bound = (jnp.einsum("tij,j->ti", prefix.Phi, phi0) + prefix.beta)
+    bound = mv(prefix.Phi, phi0) + prefix.beta
     starts = jnp.concatenate([phi0[None], bound[:-1]], axis=0)  # (T, nx)
 
     # phi at tau_{i*n + l + 1} = cum[i, l] applied to starts[i].
-    sub = (jnp.einsum("tlij,tj->tli", cum.Phi, starts) + cum.beta)
+    sub = mv(cum.Phi, starts[:, None, :]) + cum.beta
     phi = jnp.concatenate(
         [phi0[None], sub.reshape((grid.N,) + sub.shape[2:])], axis=0)
     return phi
@@ -267,7 +268,7 @@ def parallel_two_filter(
 
         def step(carry, inp):
             P, b = inp
-            nxt = P @ carry + b
+            nxt = mv(P, carry) + b
             return nxt, nxt
 
         _, phi_blk0 = jax.lax.scan(step, phi0, (Phi, beta))   # (n, nx)

@@ -38,11 +38,6 @@ from jax.sharding import PartitionSpec
 
 from repro import obs
 
-try:                                   # jax >= 0.6 top-level API
-    from jax import shard_map as _shard_map
-except ImportError:                    # older releases
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 T = TypeVar("T")
 
 
@@ -132,9 +127,7 @@ def distributed_scan(
         totals = jax.tree_util.tree_map(
             lambda x: x.astype(carry_dtype), totals)
     idx = jax.lax.axis_index(axis_name)
-    # psum of 1 == the axis size; jax.lax.axis_size is not available on
-    # every supported jax release, psum works inside shard_map on all.
-    p = jax.lax.psum(1, axis_name)
+    p = jax.lax.axis_size(axis_name)
 
     if reverse:
         # exclusive suffix of totals strictly AFTER this shard
@@ -208,10 +201,10 @@ def sharded_scan(
             obs.inc("distributed.carry_bytes", carry * shards)
 
         spec = tm(lambda _: PartitionSpec(axis_name), elems)
-        dist = _shard_map(
+        dist = jax.shard_map(
             partial(distributed_scan, fn, axis_name=axis_name,
                     reverse=reverse, carry_dtype=carry_dtype),
-            mesh=mesh, in_specs=(spec,), out_specs=spec, check_rep=False)
+            mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
 
         cut = (length // shards) * shards
         if cut == length:

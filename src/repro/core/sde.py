@@ -25,6 +25,8 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import linalg
+from .linalg import mm, mT, mv, quad
 from .types import GridLQT
 
 
@@ -170,7 +172,7 @@ def build_grid_lqt(
     MAP restricted to the window (docs/STREAMING.md).
     """
     flip = lambda a: jnp.flip(a, axis=0)
-    Rinv = jnp.linalg.inv(R)
+    Rinv = linalg.inv(R)
     if measurement_mask is not None:
         Rinv = Rinv * measurement_mask[:, None, None]
         if lin is not None:
@@ -179,7 +181,7 @@ def build_grid_lqt(
         S_T, v_T = jnp.asarray(prior[0]), jnp.asarray(prior[1])
     else:
         S_T = jnp.linalg.inv(P0)
-        v_T = S_T @ m0
+        v_T = mv(S_T, m0)
     return GridLQT(
         dt=flip(jnp.broadcast_to(dt, y.shape[:1])),
         F=-flip(F), c=-flip(c),
@@ -255,7 +257,7 @@ def _psd_sqrt(Q):
     Q = L W L^T is singular for most physical models (paper section 2.1
     allows this; only simulation needs a noise square root)."""
     w, V = jnp.linalg.eigh(Q)
-    return V @ jnp.diag(jnp.sqrt(jnp.clip(w, 0.0))) @ V.T
+    return mm(V * jnp.sqrt(jnp.clip(w, 0.0))[..., None, :], mT(V))
 
 
 def simulate_linear(model: LinearSDE, ts: Array, key: jax.Array):
@@ -263,13 +265,13 @@ def simulate_linear(model: LinearSDE, ts: Array, key: jax.Array):
     F, c, H, r, Q, R = model.grids(ts)
     dt = jnp.diff(ts)
     kx, ky, k0 = jax.random.split(key, 3)
-    x0 = model.m0 + jnp.linalg.cholesky(model.P0) @ jax.random.normal(
-        k0, model.m0.shape, dtype=model.m0.dtype)
+    x0 = model.m0 + mv(jnp.linalg.cholesky(model.P0), jax.random.normal(
+        k0, model.m0.shape, dtype=model.m0.dtype))
 
     def step(x, inp):
         Fk, ck, Qk, dtk, eps = inp
-        xn = x + dtk * (Fk @ x + ck) + jnp.sqrt(dtk) * (
-            _psd_sqrt(Qk) @ eps)
+        xn = x + dtk * (mv(Fk, x) + ck) + jnp.sqrt(dtk) * (
+            mv(_psd_sqrt(Qk), eps))
         return xn, xn
 
     eps = jax.random.normal(kx, (dt.shape[0],) + model.m0.shape,
@@ -282,8 +284,7 @@ def simulate_linear(model: LinearSDE, ts: Array, key: jax.Array):
     Rch = jnp.linalg.cholesky(R)
     # measurement for interval k uses the reversed-left point x_{k+1}
     # (backward-Euler convention, see module docstring)
-    y = (jnp.einsum("kij,kj->ki", H, xs[1:]) + r
-         + jnp.einsum("kij,kj->ki", Rch, noise) / jnp.sqrt(dt)[:, None])
+    y = (mv(H, xs[1:]) + r + mv(Rch, noise) / jnp.sqrt(dt)[:, None])
     return xs, y
 
 
@@ -293,13 +294,13 @@ def simulate_nonlinear(model: NonlinearSDE, ts: Array, key: jax.Array):
     Q = model._eval(model.Q, tl)
     R = model._eval(model.R, tl)
     kx, ky, k0 = jax.random.split(key, 3)
-    x0 = model.m0 + jnp.linalg.cholesky(model.P0) @ jax.random.normal(
-        k0, model.m0.shape, dtype=model.m0.dtype)
+    x0 = model.m0 + mv(jnp.linalg.cholesky(model.P0), jax.random.normal(
+        k0, model.m0.shape, dtype=model.m0.dtype))
 
     def step(x, inp):
         t, Qk, dtk, eps = inp
         xn = x + dtk * model.f(x, t) + jnp.sqrt(dtk) * (
-            _psd_sqrt(Qk) @ eps)
+            mv(_psd_sqrt(Qk), eps))
         return xn, xn
 
     eps = jax.random.normal(kx, (dt.shape[0],) + model.m0.shape,
@@ -310,7 +311,7 @@ def simulate_nonlinear(model: NonlinearSDE, ts: Array, key: jax.Array):
     hx = jax.vmap(model.h)(xs[1:], tl)
     Rch = jnp.linalg.cholesky(R)
     noise = jax.random.normal(ky, hx.shape, dtype=model.m0.dtype)
-    y = hx + jnp.einsum("kij,kj->ki", Rch, noise) / jnp.sqrt(dt)[:, None]
+    y = hx + mv(Rch, noise) / jnp.sqrt(dt)[:, None]
     return xs, y
 
 
@@ -320,9 +321,9 @@ def _prior_cost(model, x0: Array, prior: Optional[Prior]) -> Array:
     if prior is not None:
         S0, v0 = prior
         d0 = x0 - jnp.linalg.solve(S0, v0)
-        return 0.5 * d0 @ S0 @ d0
+        return 0.5 * quad(d0, S0)
     d0 = x0 - model.m0
-    return 0.5 * d0 @ jnp.linalg.solve(model.P0, d0)
+    return 0.5 * jnp.sum(d0 * jnp.linalg.solve(model.P0, d0))
 
 
 def om_cost_linear(model: LinearSDE, ts: Array, y: Array, x: Array,
@@ -342,12 +343,10 @@ def om_cost_linear(model: LinearSDE, ts: Array, y: Array, x: Array,
     dt = jnp.diff(ts)
     cost = _prior_cost(model, x[0], prior)
     xr = x[1:]
-    resid = (x[1:] - x[:-1]) / dt[:, None] - (
-        jnp.einsum("kij,kj->ki", F, xr) + c)
-    cost = cost + 0.5 * jnp.sum(
-        dt * jnp.einsum("ki,kij,kj->k", resid, jnp.linalg.inv(Q), resid))
-    innov = y - (jnp.einsum("kij,kj->ki", H, xr) + r)
-    meas = jnp.einsum("ki,kij,kj->k", innov, jnp.linalg.inv(R), innov)
+    resid = (x[1:] - x[:-1]) / dt[:, None] - (mv(F, xr) + c)
+    cost = cost + 0.5 * jnp.sum(dt * quad(resid, linalg.inv(Q)))
+    innov = y - (mv(H, xr) + r)
+    meas = quad(innov, linalg.inv(R))
     if measurement_mask is not None:
         meas = meas * measurement_mask
     cost = cost + 0.5 * jnp.sum(dt * meas)
@@ -368,10 +367,9 @@ def om_cost_nonlinear(
     xr = x[1:]
     fx = jax.vmap(model.f)(xr, tl)
     resid = (x[1:] - x[:-1]) / dt[:, None] - fx
-    cost = cost + 0.5 * jnp.sum(
-        dt * jnp.einsum("ki,kij,kj->k", resid, jnp.linalg.inv(Q), resid))
+    cost = cost + 0.5 * jnp.sum(dt * quad(resid, linalg.inv(Q)))
     innov = y - jax.vmap(model.h)(xr, tl)
-    meas = jnp.einsum("ki,kij,kj->k", innov, jnp.linalg.inv(R), innov)
+    meas = quad(innov, linalg.inv(R))
     if measurement_mask is not None:
         meas = meas * measurement_mask
     cost = cost + 0.5 * jnp.sum(dt * meas)
@@ -382,7 +380,8 @@ def om_cost_nonlinear(
     return cost
 
 
-def om_cost_grid(grid: GridLQT, x: Array) -> Array:
+def om_cost_grid(grid: GridLQT, x: Array,
+                 Qpinv: Optional[Array] = None) -> Array:
     """Onsager-Machlup cost of trajectory ``x`` under a built grid problem.
 
     ``x`` is in ORIGINAL time order (``(N+1, nx)``); the quadrature is the
@@ -392,21 +391,23 @@ def om_cost_grid(grid: GridLQT, x: Array) -> Array:
     intervals cost nothing).  ``Q`` may be singular (``Q = L W L^T``):
     the dynamics term uses the pseudo-inverse, i.e. the minimum-energy
     cost over noise directions the model actually drives -- identical to
-    ``inv(Q)`` whenever ``Q`` is invertible.
+    ``inv(Q)`` whenever ``Q`` is invertible.  ``Qpinv`` passes that
+    pseudo-inverse in, shared ``(nx, nx)`` or per reversed interval; by
+    default it is computed per interval, an SVD per grid point that the TPU
+    compiler handles poorly at long horizons.
     """
     phi = jnp.flip(x, axis=0)                     # phi_j = x_{N-j}
     dt = grid.dt
     resid = (phi[1:] - phi[:-1]) / dt[:, None] - (
-        jnp.einsum("kij,kj->ki", grid.F, phi[:-1]) + grid.c)
-    Qpinv = jnp.linalg.pinv(grid.Q)
-    cost = 0.5 * jnp.sum(
-        dt * jnp.einsum("ki,kij,kj->k", resid, Qpinv, resid))
-    innov = grid.y - (jnp.einsum("kij,kj->ki", grid.H, phi[:-1]) + grid.r)
-    cost = cost + 0.5 * jnp.sum(
-        dt * jnp.einsum("ki,kij,kj->k", innov, grid.Rinv, innov))
+        mv(grid.F, phi[:-1]) + grid.c)
+    if Qpinv is None:
+        Qpinv = jnp.linalg.pinv(grid.Q)
+    cost = 0.5 * jnp.sum(dt * quad(resid, Qpinv))
+    innov = grid.y - (mv(grid.H, phi[:-1]) + grid.r)
+    cost = cost + 0.5 * jnp.sum(dt * quad(innov, grid.Rinv))
     if grid.lin is not None:
-        cost = cost + jnp.sum(dt * jnp.einsum("ki,ki->k", grid.lin, phi[:-1]))
+        cost = cost + jnp.sum(dt * jnp.sum(grid.lin * phi[:-1], axis=-1))
     # terminal (reversed) boundary = the initial prior N(m0, P0)
     m0 = jnp.linalg.solve(grid.S_T, grid.v_T)
     d0 = phi[-1] - m0
-    return cost + 0.5 * d0 @ grid.S_T @ d0
+    return cost + 0.5 * quad(d0, grid.S_T)

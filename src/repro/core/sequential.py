@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import linalg
+from .linalg import mT, mm, mv
 from .combine import apply_element_to_value, elem_min_initial, lqt_combine
 from .elements import _lin_term, identity_element, one_step_elements
 from .types import GridLQT, LQTElement, MAPSolution, ValueFn
@@ -45,16 +47,18 @@ def sequential_backward(grid: GridLQT, mode: str = "euler") -> ValueFn:
 
     def step(carry, inp):
         dtk, Fk, ck, Hk, rk, Qk, Rik, yk, lk = inp
-        HtRi = Hk.T @ Rik
+        HtRi = mm(mT(Hk), Rik)
 
         def derivs(sv):
             S, v = sv
-            dS = S @ Qk @ S - S @ Fk - Fk.T @ S - HtRi @ Hk
-            dv = S @ (Qk @ v + ck) - Fk.T @ v - HtRi @ (yk - rk) + lk
+            dS = (mm(mm(S, Qk), S) - mm(S, Fk) - mm(mT(Fk), S)
+                  - mm(HtRi, Hk))
+            dv = (mv(S, mv(Qk, v) + ck) - mv(mT(Fk), v)
+                  - mv(HtRi, yk - rk) + lk)
             return (dS, dv)
 
         Sn, vn = _ode_step_backward(derivs, tuple(carry), dtk, mode)
-        Sn = 0.5 * (Sn + Sn.T)
+        Sn = 0.5 * (Sn + mT(Sn))
         nxt = ValueFn(Sn, vn)
         return nxt, nxt
 
@@ -78,20 +82,20 @@ def affine_recovery_maps(grid: GridLQT, values: ValueFn, mode: str = "euler"):
         S1 = values.S[1:]
         v1 = values.v[1:]
         I = jnp.eye(grid.nx, dtype=grid.F.dtype)
-        M = I + e.C @ S1
+        M = I + mm(e.C, S1)
         rhs = jnp.concatenate(
-            [e.A, (e.b + jnp.einsum("kij,kj->ki", e.C, v1))[..., None]],
+            [e.A, (e.b + mv(e.C, v1))[..., None]],
             axis=-1)
-        sol = jnp.linalg.solve(M, rhs)
+        sol = linalg.solve(M, rhs)
         return sol[..., :-1], sol[..., -1]
 
     S0 = values.S[:-1]
     v0 = values.v[:-1]
     dt = grid.dt[:, None, None]
     I = jnp.eye(grid.nx, dtype=grid.F.dtype)
-    Fbar = grid.F - grid.Q @ S0
+    Fbar = grid.F - mm(grid.Q, S0)
     Phi = I + dt * Fbar
-    beta = grid.dt[:, None] * (jnp.einsum("kij,kj->ki", grid.Q, v0) + grid.c)
+    beta = grid.dt[:, None] * (mv(grid.Q, v0) + grid.c)
     return Phi, beta
 
 
@@ -103,7 +107,7 @@ def sequential_rts(grid: GridLQT, mode: str = "euler") -> MAPSolution:
 
     def step(phi, inp):
         P, b = inp
-        nxt = P @ phi + b
+        nxt = mv(P, phi) + b
         return nxt, nxt
 
     _, tail = jax.lax.scan(step, phi0, (Phi, beta))
@@ -117,14 +121,14 @@ def sequential_rts(grid: GridLQT, mode: str = "euler") -> MAPSolution:
 def two_filter_combine(fwd: LQTElement, S: jnp.ndarray, v: jnp.ndarray):
     """Eq. (48): phi* = (I + Cbar S)^{-1} (bbar + Cbar v) (+ covariance)."""
     I = jnp.broadcast_to(jnp.eye(S.shape[-1], dtype=S.dtype), S.shape)
-    M = I + fwd.C @ S
+    M = I + mm(fwd.C, S)
     rhs = jnp.concatenate(
-        [(fwd.b + (fwd.C @ v[..., None])[..., 0])[..., None], fwd.C],
+        [(fwd.b + mv(fwd.C, v))[..., None], fwd.C],
         axis=-1)
-    sol = jnp.linalg.solve(M, rhs)
+    sol = linalg.solve(M, rhs)
     phi = sol[..., 0]
     cov = sol[..., 1:]
-    return phi, 0.5 * (cov + jnp.swapaxes(cov, -1, -2))
+    return phi, 0.5 * (cov + mT(cov))
 
 
 def sequential_two_filter(
@@ -154,19 +158,20 @@ def sequential_two_filter(
         def step(carry, inp):
             A, b, C, eta, J = carry
             dtk, Fk, ck, Hk, rk, Qk, Rik, yk, lk = inp
-            HtRi = Hk.T @ Rik
-            CHtRi = C @ HtRi
-            innov = HtRi @ (yk - rk)
-            dA = -CHtRi @ (Hk @ A) + Fk @ A
-            db = C @ innov + Fk @ b + ck - CHtRi @ (Hk @ b) - C @ lk
-            dC = -CHtRi @ (Hk @ C) + Qk + Fk @ C + C @ Fk.T
-            deta = A.T @ (innov - HtRi @ (Hk @ b) - lk)
-            dJ = A.T @ HtRi @ (Hk @ A)
+            HtRi = mm(mT(Hk), Rik)
+            CHtRi = mm(C, HtRi)
+            innov = mv(HtRi, yk - rk)
+            dA = -mm(CHtRi, mm(Hk, A)) + mm(Fk, A)
+            db = (mv(C, innov) + mv(Fk, b) + ck - mv(CHtRi, mv(Hk, b))
+                  - mv(C, lk))
+            dC = -mm(CHtRi, mm(Hk, C)) + Qk + mm(Fk, C) + mm(C, mT(Fk))
+            deta = mv(mT(A), innov - mv(HtRi, mv(Hk, b)) - lk)
+            dJ = mm(mm(mT(A), HtRi), mm(Hk, A))
             Cn = C + dtk * dC
             Jn = J + dtk * dJ
             nxt = LQTElement(
-                A + dtk * dA, b + dtk * db, 0.5 * (Cn + Cn.T),
-                eta + dtk * deta, 0.5 * (Jn + Jn.T))
+                A + dtk * dA, b + dtk * db, 0.5 * (Cn + mT(Cn)),
+                eta + dtk * deta, 0.5 * (Jn + mT(Jn)))
             return nxt, nxt
 
         _, fwd = jax.lax.scan(

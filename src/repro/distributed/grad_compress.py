@@ -30,9 +30,7 @@ def compressed_psum(grads, err, axis_name: str) -> Tuple[Any, Any]:
     Returns (mean_grads_f32, new_err).  Call INSIDE shard_map over the
     data-parallel axis with per-shard (unreduced) gradients.
     """
-    # psum of 1 == the axis size; jax.lax.axis_size is not available on
-    # every supported jax release, psum works inside shard_map on all.
-    size = jax.lax.psum(1, axis_name)
+    size = jax.lax.axis_size(axis_name)
 
     def one(g, e):
         target = g.astype(jnp.float32) + e
@@ -57,7 +55,6 @@ def make_compressed_dp_step(loss_fn, optimizer_update, mesh,
     -> (params, opt).  Params/opt replicated; batch sharded over
     ``axis_name``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def step(params, opt, err, batch):
@@ -69,8 +66,8 @@ def make_compressed_dp_step(loss_fn, optimizer_update, mesh,
 
     rep = P()
     batch_spec = P(axis_name)
-    return shard_map(
+    return jax.shard_map(
         step, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False)
+        check_vma=False)

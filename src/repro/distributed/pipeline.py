@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -75,9 +74,9 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_micro,
 
     spec_params = jax.tree_util.tree_map(lambda _: P(axis_name),
                                          stage_params)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x_micro)

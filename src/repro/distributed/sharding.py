@@ -346,11 +346,6 @@ def shard_over_batch(fn, mesh: Mesh, batch_axis: str,
     used by ``repro.core.batching`` / the ``TrajectoryEngine`` -- the
     complement of the time-axis ``core.pscan.distributed_scan``.
     """
-    try:                                   # jax >= 0.6 top-level API
-        from jax import shard_map
-    except ImportError:                    # older releases
-        from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(P(batch_axis) if b else P() for b in arg_batched)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                      out_specs=P(batch_axis))

@@ -26,8 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 
 def _matmat(X, Y, nx):
@@ -143,6 +143,6 @@ def lqt_combine_lanes(ops1, ops2, *, block_b: int = 512,
         out_specs=tuple(specs),
         out_shape=out_shapes,
         # lane blocks are independent element batches -> parallel grid
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(A1, b1, C1, e1, J1, A2, b2, C2, e2, J2)

@@ -12,8 +12,9 @@ combine ORDER matches the jnp scan; the per-combine arithmetic still
 differs (unpivoted Gauss-Jordan vs pivoted ``linalg.solve``), so results
 agree to tolerance, not bit-exactly.
 
-On non-TPU backends (this container) ``interpret=True`` executes the kernel
-body with the Pallas interpreter -- bit-accurate semantics, no Mosaic.
+On the CPU backend ``interpret=True`` executes the kernel body with the
+Pallas interpreter (bit-accurate semantics, no Mosaic), which is how the
+tests run it; on a TPU the kernel compiles with Mosaic.
 """
 from __future__ import annotations
 

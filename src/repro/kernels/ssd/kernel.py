@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 
 def _ssd_kernel(l_ref, dtx_ref, B_ref, C_ref, y_ref, state, *, chunk: int):
@@ -100,7 +99,7 @@ def ssd_chunked(l, dtx, B, C, *, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, chunk, P), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, L, P), dtx.dtype),
         scratch_shapes=[pltpu.VMEM((P, S), f32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(l, dtx, B, C)

@@ -38,7 +38,8 @@ def snapshot(include_trees: bool = False) -> dict:
 
 def env_fingerprint() -> dict:
     """Machine/runtime state a benchmark number depends on.  ``jax`` is
-    imported lazily; fields degrade to ``None`` without it."""
+    imported lazily; its fields are ``None`` only where it cannot be
+    imported, and a failing ``jax.devices()`` raises."""
     fp = {
         "python": platform.python_version(),
         "platform": platform.platform(),
@@ -48,17 +49,18 @@ def env_fingerprint() -> dict:
     }
     try:
         import jax
-        devs = jax.devices()
-        fp.update({
-            "jax": jax.__version__,
-            "backend": jax.default_backend(),
-            "device_kind": devs[0].device_kind if devs else None,
-            "device_count": len(devs),
-            "x64": bool(jax.config.jax_enable_x64),
-        })
-    except Exception:
+    except ImportError:
         fp.update({"jax": None, "backend": None, "device_kind": None,
                    "device_count": None, "x64": None})
+        return fp
+    devs = jax.devices()
+    fp.update({
+        "jax": jax.__version__,
+        "backend": jax.default_backend(),
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+        "x64": bool(jax.config.jax_enable_x64),
+    })
     return fp
 
 

@@ -164,3 +164,31 @@ def test_combine_psd_preserved(seed):
         np.testing.assert_allclose(M, M.T, atol=1e-9)
         w = np.linalg.eigvalsh(np.asarray(M))
         assert w.min() > -1e-8, f"lost PSD: {w}"
+
+
+@pytest.mark.parametrize("n", [1, 2, 4, 5, 8])
+def test_small_linalg_float32_forms_match_numpy(n):
+    """The float32 forms of ``core.linalg`` (elementwise products, the
+    unrolled pivoting Gauss-Jordan solve) against float64 numpy,
+    including systems that need row exchanges and a shared matrix
+    broadcast against a batch of right-hand sides."""
+    from repro.core import linalg
+
+    rng = np.random.default_rng(n)
+    A = rng.standard_normal((64, n, n)) + 3.0 * np.eye(n)
+    A[0] = np.eye(n)[::-1] + 1e-3            # zero diagonal: must pivot
+    B = rng.standard_normal((64, n, 3))
+    x = rng.standard_normal((64, n))
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    tol = dict(rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(linalg.mm(f32(A), f32(B)), A @ B, **tol)
+    np.testing.assert_allclose(linalg.mv(f32(A), f32(x)),
+                               np.einsum("bij,bj->bi", A, x), **tol)
+    np.testing.assert_allclose(linalg.quad(f32(x), f32(A)),
+                               np.einsum("bi,bij,bj->b", x, A, x), **tol)
+    np.testing.assert_allclose(linalg.solve(f32(A), f32(B)),
+                               np.linalg.solve(A, B), **tol)
+    np.testing.assert_allclose(linalg.solve(f32(A[1]), f32(B)),
+                               np.linalg.solve(A[1], B), **tol)
+    np.testing.assert_allclose(linalg.inv(f32(A)), np.linalg.inv(A), **tol)
+    assert linalg.solve(f32(A), f32(B)).dtype == jnp.float32

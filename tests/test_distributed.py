@@ -62,7 +62,7 @@ def test_grad_compression_error_feedback():
     from repro.distributed.grad_compress import (
         compressed_psum, init_error_state, make_compressed_dp_step)
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
@@ -72,7 +72,7 @@ def test_grad_compression_error_feedback():
     err = init_error_state({"w": g["w"][0]})
     f = shard_map(partial(compressed_psum, axis_name="data"),
                   mesh=mesh, in_specs=({"w": P("data")}, {"w": P()}),
-                  out_specs=({"w": P()}, {"w": P()}), check_rep=False)
+                  out_specs=({"w": P()}, {"w": P()}), check_vma=False)
     mean, new_err = f(g, err)
     exact = g["w"].mean(axis=0)
     q_err = np.abs(np.asarray(mean["w"][0]) - np.asarray(exact)).max()
@@ -119,7 +119,8 @@ def test_sharded_train_step_matches_single_device():
     step = make_train_step(cfg, tcfg)
     p_ref, o_ref, m_ref = jax.jit(step)(params, opt, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with mesh_context(mesh):
         p_sh, o_sh = make_shardings(cfg, tcfg, mesh)
         b_sh = jax.tree_util.tree_map(
@@ -150,7 +151,7 @@ def test_distributed_temporal_map_solver():
     the distributed backward scan == the single-device scan."""
     out = _run("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import (
         lqt_combine, suffix_scan, distributed_scan, grid_lqt_from_linear,
@@ -189,9 +190,9 @@ def test_distributed_temporal_map_solver():
     want = suffix_scan(lqt_combine, elems64)
     mesh = jax.make_mesh((8,), ("t",))
     spec = LQTElement(*(P("t"),) * 5)
-    f = shard_map(partial(distributed_scan, lqt_combine, axis_name="t",
-                          reverse=True),
-                  mesh=mesh, in_specs=(spec,), out_specs=spec)
+    f = jax.jit(shard_map(partial(distributed_scan, lqt_combine,
+                                  axis_name="t", reverse=True),
+                          mesh=mesh, in_specs=(spec,), out_specs=spec))
     got = f(elems64)
     import numpy as np
     for a, b in zip(got, want):

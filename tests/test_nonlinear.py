@@ -125,13 +125,13 @@ def test_x_init_warm_start(ct_problem):
     model, ts, _, y = ct_problem
     problem = Problem.single(model, ts, y)
     inner = ParallelOptions(nsub=10, mode="discrete")
-    ref = _ieks(model, "parallel_rts", inner, iterations=5).solve(problem)
+    # The IEKS contracts linearly here, by about 0.42 per pass (RMS step
+    # 1.6e-3 after pass 5): 20 passes reach a 3e-9 RMS step, so one more
+    # pass from that point moves x by about 1e-9, well inside the bound.
+    ref = _ieks(model, "parallel_rts", inner, iterations=20).solve(problem)
     warm = _ieks(model, "parallel_rts", inner, iterations=1).solve(
         Problem.single(model, ts, y, x_init=ref.x))
-    # one extra pass from the 5-iteration point still moves x by ~1e-6
-    # (the IEKS fixed point is only approached); bound the drift, don't
-    # demand exact stationarity.
-    np.testing.assert_allclose(warm.x, ref.x, atol=1e-5, rtol=0)
+    np.testing.assert_allclose(warm.x, ref.x, atol=1e-7, rtol=0)
     point = _ieks(model, "parallel_rts", inner, iterations=1).solve(
         Problem.single(model, ts, y, x_init=model.m0))
     cold = _ieks(model, "parallel_rts", inner, iterations=1).solve(problem)

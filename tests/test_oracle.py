@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (
     grid_lqt_from_linear, qp_map_from_grid, simulate_linear, time_grid,
@@ -116,3 +117,29 @@ def test_qp_oracle_self_consistency():
     a = qp_map_from_grid(grid)
     b = qp_map_estimate(model, ts, y)
     np.testing.assert_allclose(a, b, rtol=1e-8, atol=1e-8)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_rts_host_oracle_matches_qp(masked):
+    """The O(N) float64 Kalman/RTS oracle reaches the dense QP's
+    minimiser, on a time-varying model, with and without missing data."""
+    from repro.core import qp_map_estimate
+    from repro.core.oracle import rts_map_host
+    model = random_ltv(jax.random.PRNGKey(10))
+    ts = time_grid(0.0, 2.0, 40)
+    _, y = simulate_linear(model, ts, jax.random.PRNGKey(11))
+    mask = np.ones(40)
+    if masked:
+        mask[10:25] = 0.0
+    F, c, H, r, Q, R = model.grids(ts)
+    got = rts_map_host(F, c, H, r, Q, R, y, np.diff(ts), model.m0,
+                       model.P0, mask=mask)
+    if masked:
+        # the dense QP takes missing data as a huge measurement variance
+        from repro.core.oracle import _qp_solve
+        Rbig = np.array(R) * np.where(mask > 0, 1.0, 1e30)[:, None, None]
+        want = _qp_solve(F, c, H, r, Q, Rbig, y, np.diff(ts), model.m0,
+                         model.P0)
+    else:
+        want = qp_map_estimate(model, ts, y)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-8, atol=1e-8)

@@ -58,12 +58,15 @@ _DISTRIBUTED_SNIPPET = textwrap.dedent("""
     import numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core import (affine_combine, lqt_combine, prefix_scan,
                             suffix_scan, distributed_scan)
     from repro.core.types import AffineElement, LQTElement
 
-    mesh = jax.make_mesh((8,), ("t",))
+    # Auto axes, as the meshes MeshSpec builds: sharded_scan slices its
+    # output, which an Explicit-axis mesh (make_mesh's default) refuses.
+    mesh = jax.make_mesh((8,), ("t",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     T, n = 64, 3
 
@@ -72,10 +75,10 @@ _DISTRIBUTED_SNIPPET = textwrap.dedent("""
                           jnp.asarray(rng.standard_normal((T, n))))
     spec = AffineElement(P("t"), P("t"))
     for reverse in (False, True):
-        f = shard_map(
+        f = jax.jit(shard_map(
             partial(distributed_scan, affine_combine, axis_name="t",
                     reverse=reverse),
-            mesh=mesh, in_specs=(spec,), out_specs=spec)
+            mesh=mesh, in_specs=(spec,), out_specs=spec))
         got = f(elems)
         want = (suffix_scan if reverse else prefix_scan)(
             affine_combine, elems)
@@ -95,9 +98,9 @@ _DISTRIBUTED_SNIPPET = textwrap.dedent("""
         C=rand_psd(T), eta=jnp.asarray(rng.standard_normal((T, n))),
         J=rand_psd(T))
     lspec = LQTElement(*(P("t"),) * 5)
-    f = shard_map(
+    f = jax.jit(shard_map(
         partial(distributed_scan, lqt_combine, axis_name="t", reverse=True),
-        mesh=mesh, in_specs=(lspec,), out_specs=lspec)
+        mesh=mesh, in_specs=(lspec,), out_specs=lspec))
     got = f(le)
     want = suffix_scan(lqt_combine, le)
     for a, b in zip(got, want):

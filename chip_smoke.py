@@ -191,10 +191,13 @@ def offline_linear(clock, args, interpret):
     # v5e and on the CPU), so RTS_TOL = 2e4 eps = 1.2e-3 leaves a factor
     # of 8.  The two-filter method instead solves (I + C S) phi = ... at
     # every point, with C the forward filter's covariance and S the
-    # backward information: where both are confident that system is the
-    # worst conditioned in the pipeline and loses more digits (7.6e-4 on
-    # the CPU, 1.9e-3 on TPU v5e in float32 at 1e5 points), so it gets
-    # 1e5 eps = 6.0e-3, a factor of 3.
+    # backward information.  On this problem (float64, seed 0) the largest
+    # condition number of I + C S grows about as N^2: 3.5e4, 5.6e5, 8.9e6
+    # and 5.2e7 at N = 2 560, 10 240, 40 960 and 1e5 (median 24), so eps
+    # times it bounds nothing at 1e5.  Its float32 error on the CPU grows
+    # with it, 1.9e-5, 1.7e-4, 3.7e-4 and 7.6e-4 at those N, against
+    # 6.5e-6 to 1.4e-4 for parallel_rts; TPU v5e gave 1.9e-3 at 1e5.  So it
+    # gets 1e5 eps = 6.0e-3, a factor of 3 over the chip.
     methods = [
         ("sequential_rts", SequentialOptions(mode="discrete"), RTS_TOL),
         ("parallel_rts", ParallelOptions(**par), RTS_TOL),

@@ -420,10 +420,11 @@ def _solve_arrays(model: Model, spec: MethodSpec, options, ts, y, mask,
     if diagnostics:
         # In float32 (the chip) a time-invariant Q gets one pseudo-inverse,
         # taken on the host: a per-point SVD compiles for minutes on the
-        # TPU at long horizons.  Float64 (the CPU tests) keeps the
-        # per-point form: with the constant, XLA:CPU 0.9.0's
-        # concurrency-optimized scheduler returned NaN trajectories from
-        # parallel_two_filter.
+        # TPU at long horizons.  Float64 runs only on the CPU, where that
+        # constant beside core.linalg's LAPACK forms gave a NaN trajectory
+        # from parallel_two_filter, right again with XLA:CPU's
+        # concurrency-optimized scheduler turned off (PERF.md, open
+        # questions), so it keeps the per-point form.
         Qpinv = None
         if not callable(model.Q) and sol.x.dtype != jnp.float64:
             Qpinv = np.linalg.pinv(np.asarray(model.Q)).astype(sol.x.dtype)

@@ -19,35 +19,43 @@ badly at long horizons:
 
 So in float32, the chip's dtype, every function here is built from
 broadcasts, multiplies, selects and reductions over the small trailing
-axes, which XLA fuses.  In float64, which only the CPU runs natively (the
-tests run there with x64 on), they are the ``jnp`` operations, which
-LAPACK and Eigen compile and run fast.  The dtype is known when the program
-is traced, so an ahead-of-time compile for a TPU from a CPU-only process
-takes the same float32 form the chip runs.  All functions broadcast over
-arbitrary leading batch axes, like ``jnp.linalg``.
+axes, which XLA fuses.  In float64, which only the CPU runs (the tests, with
+x64 on), they are ``jnp``'s dots and LAPACK calls.  One form for both
+dtypes was tried and measured on the CPU:
+
+* the elementwise forms in float64 made XLA:CPU's compiles about three
+  times slower (the unrolled elimination) and the full test suite 1.7
+  times slower;
+* elementwise products with LAPACK solves gave a wrong trajectory for one
+  record of a time-sharded stacked solve, right again with XLA:CPU's
+  concurrency-optimized scheduler turned off (PERF.md, open questions).
+
+The float32 forms run end to end on the CPU in
+``tests/test_float32_smoothers.py``.  The dtype is known when the program is
+traced, so an ahead-of-time compile for a TPU from a CPU-only process takes
+the float32 form the chip runs.  All functions broadcast over arbitrary
+leading batch axes, like ``jnp.linalg``.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 
-def _by_dtype(f64, default):
-    """One function that runs ``f64`` on float64 operands, else ``default``."""
-    def fn(*args):
-        if any(jnp.result_type(a) == jnp.float64 for a in args):
-            return f64(*args)
-        return default(*args)
-    fn.__doc__ = default.__doc__
-    return fn
+def _f64(*args) -> bool:
+    return any(jnp.result_type(a) == jnp.float64 for a in args)
 
 
-def _mm(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+def mm(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """Matrix product ``A B`` of ``(..., n, k)`` and ``(..., k, m)``."""
+    if _f64(A, B):
+        return jnp.matmul(A, B)
     return jnp.sum(A[..., :, :, None] * B[..., None, :, :], axis=-2)
 
 
-def _mv(A: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+def mv(A: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     """Matrix-vector product ``A x`` of ``(..., n, k)`` and ``(..., k)``."""
+    if _f64(A, x):
+        return jnp.matmul(A, x[..., None])[..., 0]
     return jnp.sum(A * x[..., None, :], axis=-1)
 
 
@@ -61,9 +69,11 @@ def quad(x: jnp.ndarray, M: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(x * mv(M, x), axis=-1)
 
 
-def _solve(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
+def solve(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     """``A^{-1} B`` for ``A`` ``(..., n, n)`` and ``B`` ``(..., n, m)``, by
-    Gauss-Jordan elimination with partial pivoting."""
+    Gauss-Jordan elimination with partial pivoting (LAPACK in float64)."""
+    if _f64(A, B):
+        return jnp.linalg.solve(A, B)
     n = A.shape[-1]
     batch = jnp.broadcast_shapes(A.shape[:-2], B.shape[:-2])
     dtype = jnp.result_type(A, B)
@@ -91,13 +101,10 @@ def _solve(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([r[..., n:] for r in rows], axis=-2)
 
 
-def _inv(A: jnp.ndarray) -> jnp.ndarray:
+def inv(A: jnp.ndarray) -> jnp.ndarray:
     """``A^{-1}`` for ``A`` ``(..., n, n)``."""
+    if _f64(A):
+        return jnp.linalg.inv(A)
     n = A.shape[-1]
-    return _solve(A, jnp.broadcast_to(jnp.eye(n, dtype=A.dtype), A.shape))
+    return solve(A, jnp.broadcast_to(jnp.eye(n, dtype=A.dtype), A.shape))
 
-
-mm = _by_dtype(jnp.matmul, _mm)
-mv = _by_dtype(lambda A, x: jnp.matmul(A, x[..., None])[..., 0], _mv)
-solve = _by_dtype(jnp.linalg.solve, _solve)
-inv = _by_dtype(jnp.linalg.inv, _inv)

@@ -168,10 +168,23 @@ def test_combine_psd_preserved(seed):
 
 @pytest.mark.parametrize("n", [1, 2, 4, 5, 8])
 def test_small_linalg_float32_forms_match_numpy(n):
-    """The float32 forms of ``core.linalg`` (elementwise products, the
-    unrolled pivoting Gauss-Jordan solve) against float64 numpy,
-    including systems that need row exchanges and a shared matrix
-    broadcast against a batch of right-hand sides."""
+    """``core.linalg`` (elementwise products, the unrolled pivoting
+    Gauss-Jordan solve) in float32, the chip's dtype, against float64
+    numpy."""
+    _check_small_linalg(n, jnp.float32, tol=1e-4)
+
+
+@pytest.mark.parametrize("n", [1, 2, 4, 5, 8])
+def test_small_linalg_float64_matches_numpy(n):
+    """The same functions in float64, which the CPU tests run: ``jnp``'s
+    dots and LAPACK calls."""
+    _check_small_linalg(n, jnp.float64, tol=1e-10)
+
+
+def _check_small_linalg(n, dtype, tol):
+    """Products and solves against numpy, including systems that need row
+    exchanges and a shared matrix broadcast against a batch of right-hand
+    sides."""
     from repro.core import linalg
 
     rng = np.random.default_rng(n)
@@ -179,16 +192,16 @@ def test_small_linalg_float32_forms_match_numpy(n):
     A[0] = np.eye(n)[::-1] + 1e-3            # zero diagonal: must pivot
     B = rng.standard_normal((64, n, 3))
     x = rng.standard_normal((64, n))
-    f32 = lambda a: jnp.asarray(a, jnp.float32)
-    tol = dict(rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(linalg.mm(f32(A), f32(B)), A @ B, **tol)
-    np.testing.assert_allclose(linalg.mv(f32(A), f32(x)),
+    cast = lambda a: jnp.asarray(a, dtype)
+    tol = dict(rtol=tol, atol=tol)
+    np.testing.assert_allclose(linalg.mm(cast(A), cast(B)), A @ B, **tol)
+    np.testing.assert_allclose(linalg.mv(cast(A), cast(x)),
                                np.einsum("bij,bj->bi", A, x), **tol)
-    np.testing.assert_allclose(linalg.quad(f32(x), f32(A)),
+    np.testing.assert_allclose(linalg.quad(cast(x), cast(A)),
                                np.einsum("bi,bij,bj->b", x, A, x), **tol)
-    np.testing.assert_allclose(linalg.solve(f32(A), f32(B)),
+    np.testing.assert_allclose(linalg.solve(cast(A), cast(B)),
                                np.linalg.solve(A, B), **tol)
-    np.testing.assert_allclose(linalg.solve(f32(A[1]), f32(B)),
+    np.testing.assert_allclose(linalg.solve(cast(A[1]), cast(B)),
                                np.linalg.solve(A[1], B), **tol)
-    np.testing.assert_allclose(linalg.inv(f32(A)), np.linalg.inv(A), **tol)
-    assert linalg.solve(f32(A), f32(B)).dtype == jnp.float32
+    np.testing.assert_allclose(linalg.inv(cast(A)), np.linalg.inv(A), **tol)
+    assert linalg.solve(cast(A), cast(B)).dtype == dtype

@@ -33,6 +33,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -127,6 +128,19 @@ class ExecutableCache:
 
 
 _CACHE = ExecutableCache()
+# Ids shared by the host spans of one solve (the profiler's solve_id).
+_SOLVE_IDS = itertools.count()
+
+
+def _metadata_in_cache_key():
+    """While a fresh executable compiles, key its persistent-cache entry
+    by the module's metadata too.  JAX strips the metadata from the key by
+    default, so the named scopes do not change it: an entry written by a
+    build with the same operations and other scopes (or none) would be
+    handed back, and a profile would show that build's ``op_name``\\ s."""
+    from jax._src import config as jax_config
+
+    return jax_config.compilation_cache_include_metadata_in_key(True)
 
 
 def cache_stats() -> Dict[str, int]:
@@ -270,20 +284,23 @@ class Problem:
         (``P0^{-1}``, ``P0^{-1} m0``) replacing the model's ``(m0, P0)``
         -- fixed-lag window solves pass the forward-filter information at
         the window's left edge here (see docs/STREAMING.md)."""
-        ts = jnp.asarray(ts)
-        y = jnp.asarray(y)
-        if y.ndim != 2 or y.shape[0] < 1:
-            raise ValueError(f"y must be (N, ny) with N >= 1, got {y.shape}")
-        N = y.shape[0]
-        if ts.shape != (N + 1,):
-            raise ValueError(f"ts must be (N+1,) = {(N + 1,)}, got {ts.shape}")
-        _check_ny(model, y)
-        if measurement_mask is not None:
-            measurement_mask = _check_mask(measurement_mask, (N,))
-        x_init = _check_x_init(model, x_init, N, None)
-        prior = _check_prior(model, prior, None)
-        return cls(model, ts, y, measurement_mask, x_init, prior,
-                   kind="single")
+        with obs.trace_span("estimator.problem"):
+            ts = jnp.asarray(ts)
+            y = jnp.asarray(y)
+            if y.ndim != 2 or y.shape[0] < 1:
+                raise ValueError(
+                    f"y must be (N, ny) with N >= 1, got {y.shape}")
+            N = y.shape[0]
+            if ts.shape != (N + 1,):
+                raise ValueError(
+                    f"ts must be (N+1,) = {(N + 1,)}, got {ts.shape}")
+            _check_ny(model, y)
+            if measurement_mask is not None:
+                measurement_mask = _check_mask(measurement_mask, (N,))
+            x_init = _check_x_init(model, x_init, N, None)
+            prior = _check_prior(model, prior, None)
+            return cls(model, ts, y, measurement_mask, x_init, prior,
+                       kind="single")
 
     @classmethod
     def stacked(cls, model: Model, ts, ys, *, measurement_mask=None,
@@ -299,26 +316,29 @@ class Problem:
         ``prior`` ``(S0, v0)``: shared ``(nx, nx)``/``(nx,)`` or
         per-record ``(B, nx, nx)``/``(B, nx)`` information-form initial
         boundaries (see :meth:`single`)."""
-        ys = jnp.asarray(ys)
-        if ys.ndim != 3:
-            raise ValueError(f"ys must be (B, N, ny), got shape {ys.shape}")
-        ts = jnp.asarray(ts)
-        B, N = ys.shape[0], ys.shape[1]
-        if ts.shape[-1] != N + 1:
-            raise ValueError(
-                f"ts has {ts.shape[-1]} points but ys has {N} intervals "
-                f"(need N+1 = {N + 1})")
-        if ts.ndim == 2 and ts.shape[0] != B:
-            raise ValueError(f"ts batch {ts.shape[0]} != ys batch {B}")
-        if ts.ndim not in (1, 2):
-            raise ValueError(f"ts must be (N+1,) or (B, N+1), got {ts.shape}")
-        _check_ny(model, ys)
-        if measurement_mask is not None:
-            measurement_mask = _check_mask(measurement_mask, (B, N))
-        x_init = _check_x_init(model, x_init, N, B)
-        prior = _check_prior(model, prior, B)
-        return cls(model, ts, ys, measurement_mask, x_init, prior,
-                   kind="stacked")
+        with obs.trace_span("estimator.problem"):
+            ys = jnp.asarray(ys)
+            if ys.ndim != 3:
+                raise ValueError(
+                    f"ys must be (B, N, ny), got shape {ys.shape}")
+            ts = jnp.asarray(ts)
+            B, N = ys.shape[0], ys.shape[1]
+            if ts.shape[-1] != N + 1:
+                raise ValueError(
+                    f"ts has {ts.shape[-1]} points but ys has {N} intervals "
+                    f"(need N+1 = {N + 1})")
+            if ts.ndim == 2 and ts.shape[0] != B:
+                raise ValueError(f"ts batch {ts.shape[0]} != ys batch {B}")
+            if ts.ndim not in (1, 2):
+                raise ValueError(
+                    f"ts must be (N+1,) or (B, N+1), got {ts.shape}")
+            _check_ny(model, ys)
+            if measurement_mask is not None:
+                measurement_mask = _check_mask(measurement_mask, (B, N))
+            x_init = _check_x_init(model, x_init, N, B)
+            prior = _check_prior(model, prior, B)
+            return cls(model, ts, ys, measurement_mask, x_init, prior,
+                       kind="stacked")
 
     @classmethod
     def ragged(cls, model: Model, records: Records, *, x_init=None,
@@ -428,7 +448,8 @@ def _solve_arrays(model: Model, spec: MethodSpec, options, ts, y, mask,
         Qpinv = None
         if not callable(model.Q) and sol.x.dtype != jnp.float64:
             Qpinv = np.linalg.pinv(np.asarray(model.Q)).astype(sol.x.dtype)
-        cost = om_cost_grid(grid, sol.x, Qpinv)
+        with jax.named_scope("estimator.cost"):
+            cost = om_cost_grid(grid, sol.x, Qpinv)
     return Solution(x=sol.x, S=sol.S, v=sol.v, cov=sol.cov, cost=cost)
 
 
@@ -764,57 +785,46 @@ class Estimator:
         each carrying the shared
         :class:`~repro.core.types.PaddingReport`).
 
-        While ``repro.obs`` is enabled (and ``diagnostics`` is on -- the
-        hot-serving opt-out also silences instrumentation) the solve is
-        measured: phase spans ``estimator.solve.{prepare,compile,execute,
-        host_transfer}``, the ``cache.compile_seconds`` histogram for
-        fresh executables, and nonlinear iteration metrics.  The measured
-        path blocks on the result (spans time real work, not dispatch);
-        outputs are bit-exact either way.
+        The call dispatches and returns without waiting for the device,
+        whether or not ``repro.obs`` is enabled.  Its host spans,
+        ``estimator.solve`` around ``estimator.prepare`` (key and cache
+        lookup) and ``estimator.dispatch`` (the call of a cached
+        executable) or ``estimator.compile`` (the first call of a fresh
+        one, which traces and compiles, its persistent-cache entry keyed by
+        the module's metadata too), are profiler annotations sharing one
+        ``solve_id``.  While obs is enabled they are recorded, with
+        ``cache.compile_seconds`` for fresh executables and counters and
+        gauges set from the static options alone.
         """
         if problem.kind == "ragged":
             return self._solve_ragged(problem)
-        if not (self.diagnostics and obs.enabled()):
-            # hot path: no obs objects touched, fully async dispatch
-            with self._mesh_scope():
-                fn, args, _ = self._prepare(problem)
-                return fn(*args)
-        with obs.trace_span("estimator.solve"):
-            with obs.trace_span("estimator.solve.prepare"):
+        sid = next(_SOLVE_IDS)
+        with obs.trace_span("estimator.solve", solve_id=sid), \
+                self._mesh_scope():
+            with obs.trace_span("estimator.prepare", solve_id=sid):
                 fn, args, fresh = self._prepare(problem)
-            phase = ("estimator.solve.compile" if fresh
-                     else "estimator.solve.execute")
+            phase = "estimator.compile" if fresh else "estimator.dispatch"
             t0 = time.perf_counter()
-            with obs.trace_span(phase, xla=True), self._mesh_scope():
+            with obs.trace_span(phase, solve_id=sid), (
+                    _metadata_in_cache_key() if fresh
+                    else contextlib.nullcontext()):
                 out = fn(*args)
-                jax.block_until_ready(out)
             if fresh:
-                obs.record("cache.compile_seconds",
-                           time.perf_counter() - t0)
-            with obs.trace_span("estimator.solve.host_transfer"):
-                self._record_solution_metrics(out)
+                obs.record("cache.compile_seconds", time.perf_counter() - t0)
+        if obs.enabled():
+            self._record_solve_metrics()
         return out
 
-    def _record_solution_metrics(self, sol: Solution) -> None:
-        """Host-side readout of per-solve diagnostics into the registry
-        (concrete device arrays only -- never called from traced code)."""
+    def _record_solve_metrics(self) -> None:
+        """Per-solve counters and gauges, from the static options alone:
+        nothing is read from the device."""
         obs.inc("estimator.solves")
         if isinstance(self.options, IteratedOptions):
             lin = self.options.linearization
             obs.inc(f"linearize.{lin.obs_name}.solves")
             obs.set_gauge("linearize.sigma_points",
                           lin.num_points(self.model.nx))
-        if sol.cost is not None:
-            obs.record("estimator.final_cost", np.mean(np.asarray(sol.cost)))
-        if sol.cost_trace is not None:
-            trace = np.asarray(sol.cost_trace)
-            obs.set_gauge("nonlinear.iterations", trace.shape[-1])
-            obs.record("nonlinear.cost_decrease",
-                       float(np.mean(trace[..., 0] - trace[..., -1])))
-        if sol.step_norms is not None:
-            steps = np.asarray(sol.step_norms)
-            obs.record("nonlinear.final_step_norm",
-                       float(np.mean(steps[..., -1])))
+            obs.set_gauge("nonlinear.iterations", self.options.iterations)
 
     def lower(self, problem: Problem) -> "jax.stages.Lowered":
         """Ahead-of-time path: the ``jax.stages.Lowered`` for this
@@ -881,7 +891,7 @@ class Estimator:
                 out[i] = slice_solution(sol, row, lengths[i])
 
         report = PaddingReport(lengths=tuple(lengths), buckets=tuple(infos))
-        if self.diagnostics and obs.enabled():
+        if obs.enabled():
             obs.inc("padding.records", report.records)
             obs.inc("padding.real_intervals", report.real_intervals)
             obs.inc("padding.solved_intervals", report.solved_intervals)

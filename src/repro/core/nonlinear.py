@@ -93,19 +93,25 @@ def iterated_solve(
         x_init = jnp.broadcast_to(x_init, (N + 1,) + x_init.shape)
 
     def cost_of(x):
-        return om_cost_nonlinear(
-            model, ts, y, x, divergence_correction=divergence_correction,
-            measurement_mask=measurement_mask, prior=prior)
+        with jax.named_scope("estimator.cost"):
+            return om_cost_nonlinear(
+                model, ts, y, x, divergence_correction=divergence_correction,
+                measurement_mask=measurement_mask, prior=prior)
 
     def step_norm(x_new, x_old):
-        return jnp.sqrt(jnp.mean(jnp.square(x_new - x_old)))
+        with jax.named_scope("estimator.cost"):
+            return jnp.sqrt(jnp.mean(jnp.square(x_new - x_old)))
+
+    def linearize(xbar):
+        with jax.named_scope("nonlinear.linearize"):
+            return grid_lqt_from_nonlinear(
+                model, ts, y, xbar,
+                divergence_correction=divergence_correction,
+                measurement_mask=measurement_mask, prior=prior,
+                linearization=linearization)
 
     def body(xbar, _):
-        grid = grid_lqt_from_nonlinear(
-            model, ts, y, xbar, divergence_correction=divergence_correction,
-            measurement_mask=measurement_mask, prior=prior,
-            linearization=linearization)
-        sol = solver(grid)
+        sol = solver(linearize(xbar))
         aux = ((cost_of(sol.x), step_norm(sol.x, xbar))
                if track_costs else None)
         return sol.x, aux
@@ -114,11 +120,7 @@ def iterated_solve(
     # iteration count), plus one final pass returning the full solution --
     # ``iterations`` linearise+solve passes total, matching the paper.
     x_last, aux = jax.lax.scan(body, x_init, None, length=iterations - 1)
-    grid = grid_lqt_from_nonlinear(
-        model, ts, y, x_last, divergence_correction=divergence_correction,
-        measurement_mask=measurement_mask, prior=prior,
-        linearization=linearization)
-    sol = solver(grid)
+    sol = solver(linearize(x_last))
     if not track_costs:
         return sol, None, None
     costs, steps = aux

@@ -71,43 +71,52 @@ def parallel_backward(
     scan elements, and ``sub_elems`` the per-substep elements (``discrete``
     mode only, else None).
     """
-    if mode == "discrete":
-        blocks, sub = discrete_block_elements(grid, nsub)
-    elif mode in ("euler", "rk4"):
-        blocks = euler_block_elements(grid, nsub, integrator=mode)
-        sub = None
-    else:
-        raise ValueError(f"unknown element mode: {mode}")
+    # The named scopes (smoother.elements / .scan / .fill, and .recover in
+    # the recoveries) label the device ops of each stage in a profile,
+    # whichever scan backend runs.
+    with jax.named_scope("smoother.elements"):
+        if mode == "discrete":
+            blocks, sub = discrete_block_elements(grid, nsub)
+        elif mode in ("euler", "rk4"):
+            blocks = euler_block_elements(grid, nsub, integrator=mode)
+            sub = None
+        else:
+            raise ValueError(f"unknown element mode: {mode}")
+        elems = _append_elem(blocks, terminal_element(grid))
 
-    elems = _append_elem(blocks, terminal_element(grid))
-    if suffix_scan_fn is not None:
-        sbar = suffix_scan_fn(elems)
-    else:
-        sbar = pscan.suffix_scan(combine_fn, elems)
+    with jax.named_scope("smoother.scan"):
+        if suffix_scan_fn is not None:
+            sbar = suffix_scan_fn(elems)
+        else:
+            sbar = pscan.suffix_scan(combine_fn, elems)
     boundary = ValueFn(sbar.J, sbar.eta)                      # (T+1, ...)
 
-    right = ValueFn(boundary.S[1:], boundary.v[1:])           # (T, ...)
-    if mode == "discrete":
-        interior = backward_value_fill_discrete(sub, right)   # (T, n, ...)
-    else:
-        interior = backward_value_fill_euler(grid, nsub, right,
-                                             integrator=mode)
+    with jax.named_scope("smoother.fill"):
+        right = ValueFn(boundary.S[1:], boundary.v[1:])       # (T, ...)
+        if mode == "discrete":
+            interior = backward_value_fill_discrete(sub, right)  # (T, n, ...)
+        else:
+            interior = backward_value_fill_euler(grid, nsub, right,
+                                                 integrator=mode)
 
-    # Replace each block's left point with the scan-combined boundary value
-    # (identical in discrete mode; the parallel-consistent choice in euler
-    # mode), then flatten to the full (N+1) substep grid.
-    S_blk = interior.S.at[:, 0].set(boundary.S[:-1])
-    v_blk = interior.v.at[:, 0].set(boundary.v[:-1])
-    N = grid.N
-    values_full = ValueFn(
-        jnp.concatenate(
-            [S_blk.reshape((N,) + S_blk.shape[2:]), boundary.S[-1:]], axis=0),
-        jnp.concatenate(
-            [v_blk.reshape((N,) + v_blk.shape[2:]), boundary.v[-1:]], axis=0),
-    )
+        # Replace each block's left point with the scan-combined boundary
+        # value (identical in discrete mode; the parallel-consistent choice
+        # in euler mode), then flatten to the full (N+1) substep grid.
+        S_blk = interior.S.at[:, 0].set(boundary.S[:-1])
+        v_blk = interior.v.at[:, 0].set(boundary.v[:-1])
+        N = grid.N
+        values_full = ValueFn(
+            jnp.concatenate(
+                [S_blk.reshape((N,) + S_blk.shape[2:]), boundary.S[-1:]],
+                axis=0),
+            jnp.concatenate(
+                [v_blk.reshape((N,) + v_blk.shape[2:]), boundary.v[-1:]],
+                axis=0),
+        )
     return values_full, boundary, blocks, sub
 
 
+@jax.named_scope("smoother.recover")
 def _recover_affine(grid: GridLQT, values_full: ValueFn, nsub: int,
                     mode: str,
                     prefix_scan_fn: Optional[Callable] = None) -> jnp.ndarray:
@@ -135,10 +144,11 @@ def _recover_affine(grid: GridLQT, values_full: ValueFn, nsub: int,
     cum, totals = jax.vmap(block)(maps)           # (T, n, ...), (T, ...)
 
     # Global prefix scan over block totals (eqs. 45-46).
-    if prefix_scan_fn is not None:
-        prefix = prefix_scan_fn(totals)                       # (T, ...)
-    else:
-        prefix = pscan.prefix_scan(affine_combine, totals)    # (T, ...)
+    with jax.named_scope("smoother.scan"):
+        if prefix_scan_fn is not None:
+            prefix = prefix_scan_fn(totals)                   # (T, ...)
+        else:
+            prefix = pscan.prefix_scan(affine_combine, totals)  # (T, ...)
 
     phi0 = jnp.linalg.solve(values_full.S[0], values_full.v[0])
     bound = mv(prefix.Phi, phi0) + prefix.beta
@@ -206,16 +216,26 @@ def parallel_two_filter(
     """
     values_full, boundary, blocks, sub = parallel_backward(
         grid, nsub, mode, combine_fn=combine_fn)
-    T = grid.N // nsub
-    nx = grid.nx
 
     # Forward prefix scan of [e (x) a_0, a_1, ..., a_{T-1}]  (eqs. 49-50).
-    a0 = jax.tree_util.tree_map(lambda a: a[0], blocks)
-    a0bar = elem_min_initial(a0, jitter=jitter)
-    rest = jax.tree_util.tree_map(lambda a: a[1:], blocks)
-    fwd_elems = _prepend_elem(a0bar, rest)
-    fwd = pscan.prefix_scan(combine_fn, fwd_elems)            # (T, ...)
+    with jax.named_scope("smoother.elements"):
+        a0 = jax.tree_util.tree_map(lambda a: a[0], blocks)
+        a0bar = elem_min_initial(a0, jitter=jitter)
+        rest = jax.tree_util.tree_map(lambda a: a[1:], blocks)
+        fwd_elems = _prepend_elem(a0bar, rest)
+    with jax.named_scope("smoother.scan"):
+        fwd = pscan.prefix_scan(combine_fn, fwd_elems)        # (T, ...)
+    return _two_filter_recover(grid, nsub, mode, values_full, boundary, sub,
+                               fwd, jitter, block0_fill, tf_fill)
 
+
+@jax.named_scope("smoother.recover")
+def _two_filter_recover(grid, nsub, mode, values_full, boundary, sub, fwd,
+                        jitter, block0_fill, tf_fill) -> MAPSolution:
+    """Eq. (48) at the block boundaries, the interior fills and block 0 of
+    :func:`parallel_two_filter`, from the forward prefix scan ``fwd``."""
+    T = grid.N // nsub
+    nx = grid.nx
     # Block-boundary states via eq. (48).
     phi_b, cov_b = two_filter_combine(fwd, boundary.S[1:], boundary.v[1:])
     phi0 = jnp.linalg.solve(boundary.S[0], boundary.v[0])

@@ -182,9 +182,10 @@ def sharded_scan(
     With ``repro.obs`` enabled, each TRACE of a sharded scan counts
     ``distributed.shards`` (time-shards used) and
     ``distributed.carry_bytes`` (bytes of per-shard carries all-gathered
-    onto every device), and spans ``span.distributed_scan`` -- static
-    shapes, so cached executables do not re-count (same convention as the
-    ``kernel.*`` counters, see docs/OBSERVABILITY.md).
+    onto every device) -- static shapes, so cached executables do not
+    re-count (same convention as the ``kernel.*`` counters, see
+    docs/OBSERVABILITY.md).  On the device its ops fall under the
+    caller's ``smoother.scan`` scope.
     """
     tm = jax.tree_util.tree_map
     leaves = jax.tree_util.tree_leaves(elems)
@@ -193,33 +194,32 @@ def sharded_scan(
     if shards < 2 or length < 2 * shards:
         return suffix_scan(fn, elems) if reverse else prefix_scan(fn, elems)
 
-    with obs.trace_span("distributed_scan"):
-        if obs.enabled():
-            carry = sum(
-                l.dtype.itemsize * math.prod(l.shape[1:]) for l in leaves)
-            obs.inc("distributed.shards", shards)
-            obs.inc("distributed.carry_bytes", carry * shards)
+    if obs.enabled():
+        carry = sum(
+            l.dtype.itemsize * math.prod(l.shape[1:]) for l in leaves)
+        obs.inc("distributed.shards", shards)
+        obs.inc("distributed.carry_bytes", carry * shards)
 
-        spec = tm(lambda _: PartitionSpec(axis_name), elems)
-        dist = jax.shard_map(
-            partial(distributed_scan, fn, axis_name=axis_name,
-                    reverse=reverse, carry_dtype=carry_dtype),
-            mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
+    spec = tm(lambda _: PartitionSpec(axis_name), elems)
+    dist = jax.shard_map(
+        partial(distributed_scan, fn, axis_name=axis_name,
+                reverse=reverse, carry_dtype=carry_dtype),
+        mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False)
 
-        cut = (length // shards) * shards
-        if cut == length:
-            return dist(elems)
-        # Non-divisible T: distributed head + local tail, one broadcast
-        # combine to stitch (fn broadcasts a rank-reduced operand).
-        head = tm(lambda x: x[:cut], elems)
-        tail = tm(lambda x: x[cut:], elems)
-        cat = lambda a, b: jnp.concatenate([a, b], axis=0)
-        if reverse:
-            tail_suf = suffix_scan(fn, tail)
-            tail_total = tm(lambda x: x[0], tail_suf)
-            head_out = fn(dist(head), tail_total)
-            return tm(cat, head_out, tail_suf)
-        head_out = dist(head)
-        head_total = tm(lambda x: x[-1], head_out)
-        tail_out = fn(head_total, prefix_scan(fn, tail))
-        return tm(cat, head_out, tail_out)
+    cut = (length // shards) * shards
+    if cut == length:
+        return dist(elems)
+    # Non-divisible T: distributed head + local tail, one broadcast
+    # combine to stitch (fn broadcasts a rank-reduced operand).
+    head = tm(lambda x: x[:cut], elems)
+    tail = tm(lambda x: x[cut:], elems)
+    cat = lambda a, b: jnp.concatenate([a, b], axis=0)
+    if reverse:
+        tail_suf = suffix_scan(fn, tail)
+        tail_total = tm(lambda x: x[0], tail_suf)
+        head_out = fn(dist(head), tail_total)
+        return tm(cat, head_out, tail_suf)
+    head_out = dist(head)
+    head_total = tm(lambda x: x[-1], head_out)
+    tail_out = fn(head_total, prefix_scan(fn, tail))
+    return tm(cat, head_out, tail_out)

@@ -116,11 +116,10 @@ class SLR(Linearization):
             obs.inc("linearize.slr.regressions", xb.shape[0])
             obs.inc("linearize.slr.sigma_points",
                     xb.shape[0] * self.family.num_points(xb.shape[-1]))
-        with obs.trace_span("slr"):
-            def one(x, t, c):
-                return slr_linearize_point(g, x, t, c, self.family,
-                                           self.spread)
-            return jax.vmap(one)(xb, tl, covs)
+
+        def one(x, t, c):
+            return slr_linearize_point(g, x, t, c, self.family, self.spread)
+        return jax.vmap(one)(xb, tl, covs)
 
     @property
     def obs_name(self) -> str:

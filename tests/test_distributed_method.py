@@ -232,22 +232,20 @@ def test_agreement_all_layouts_8_devices():
         close(a.x, b.x)
         assert a.padding is not None
 
-    # --- obs: distributed.shards / carry_bytes counters + scan span
+    # --- obs: distributed.shards / carry_bytes counters + scan scope
     import repro.obs as obs
     obs.enable(); obs.reset()
     ts2 = time_grid(cfg.t0, cfg.tf, 480)     # new length -> fresh trace
     _, y2 = simulate_linear(model, ts2, jax.random.PRNGKey(7))
-    dist.solve(Problem.single(model, ts2, y2))
-    snap = obs.snapshot(include_trees=True)
+    p2 = Problem.single(model, ts2, y2)
+    dist.solve(p2)
+    snap = obs.snapshot()
     # two sharded scans per solve (backward LQT + forward affine)
     assert snap["counters"]["distributed.shards"] == 16, snap["counters"]
     assert snap["counters"]["distributed.carry_bytes"] > 0
-    names = set()
-    def walk(nodes):
-        for nd in nodes:
-            names.add(nd["name"]); walk(nd.get("children", []))
-    walk(snap["span_trees"])
-    assert "distributed_scan" in names, names
+    # the sharded scan's ops carry the smoother.scan scope
+    text = dist.lower(p2).as_text(debug_info=True)
+    assert "smoother.scan" in text
     print("LAYOUTS-OK")
     """)
     assert "LAYOUTS-OK" in out

@@ -18,6 +18,7 @@ from repro.core import (
     Problem,
     SequentialOptions,
     cache_stats,
+    get_method,
     simulate_linear,
     simulate_nonlinear,
     time_grid,
@@ -166,13 +167,25 @@ def test_solve_phases_and_cache_metrics():
     assert snap["counters"]["estimator.solves"] == 2
     assert snap["histograms"]["cache.compile_seconds"]["count"] == 1
     h = snap["histograms"]
+    assert h["span.estimator.problem"]["count"] == 2
     assert h["span.estimator.solve"]["count"] == 2
-    assert h["span.estimator.solve.prepare"]["count"] == 2
-    assert h["span.estimator.solve.compile"]["count"] == 1
-    assert h["span.estimator.solve.execute"]["count"] == 1
-    # compile span covers the first-run compile: must dominate execute
-    assert (h["span.estimator.solve.compile"]["max"]
-            > h["span.estimator.solve.execute"]["min"])
+    assert h["span.estimator.prepare"]["count"] == 2
+    assert h["span.estimator.compile"]["count"] == 1
+    assert h["span.estimator.dispatch"]["count"] == 1
+    # the compile span holds the first call's trace and compile: it must
+    # outlast the cached executable's dispatch
+    assert (h["span.estimator.compile"]["max"]
+            > h["span.estimator.dispatch"]["min"])
+    solves = [t for t in obs.span_trees() if t["name"] == "estimator.solve"]
+    assert [[c["name"] for c in t["children"]] for t in solves] == [
+        ["estimator.prepare", "estimator.compile"],
+        ["estimator.prepare", "estimator.dispatch"]]
+    for t in solves:
+        ids = {c["metadata"]["solve_id"] for c in t["children"]}
+        assert ids == {t["metadata"]["solve_id"]}
+        assert t["t0_ns"] <= t["children"][0]["t0_ns"]
+        assert t["children"][-1]["t1_ns"] <= t["t1_ns"]
+    assert solves[0]["metadata"] != solves[1]["metadata"]
 
 
 def test_nonlinear_iteration_metrics_and_step_norms():
@@ -188,26 +201,37 @@ def test_nonlinear_iteration_metrics_and_step_norms():
     steps = np.asarray(sol.step_norms)
     assert steps.shape == (5,)
     assert steps[-1] < steps[0]          # Gauss-Newton converging
+    trace = np.asarray(sol.cost_trace)
+    assert trace.shape == (5,)
+    assert trace[-1] <= trace[0]
+    assert float(sol.cost) == trace[-1]
     snap = obs.snapshot()
     assert snap["gauges"]["nonlinear.iterations"] == 5
-    assert snap["histograms"]["nonlinear.final_step_norm"]["count"] == 1
-    assert snap["histograms"]["nonlinear.cost_decrease"]["count"] == 1
+    assert snap["counters"]["linearize.taylor.solves"] == 1
+    # the solution's diagnostics stay on the device: no host readback of
+    # them lands in the registry
+    assert not {"estimator.final_cost", "nonlinear.cost_decrease",
+                "nonlinear.final_step_norm"} & set(snap["histograms"])
+    assert snap["histograms"]["span.estimator.compile"]["count"] == 1
 
 
 def test_diagnostics_false_keeps_hot_path_silent():
+    """``diagnostics=False`` skips the cost and records no diagnostics;
+    the solve takes the same one path, spans and all."""
     obs.enable()
     model, ts, y = _linear_problem()
     est = Estimator(model, method="sequential_rts",
                     options=SequentialOptions(), diagnostics=False)
     sol = est.solve(Problem.single(model, ts, y))
-    assert sol.cost is None                  # diagnostics skipped
+    assert sol.cost is None and sol.cost_trace is None  # diagnostics skipped
     snap = obs.snapshot()
-    assert "estimator.solves" not in snap["counters"]
-    # the fast path allocates NO obs instruments: the only registry
-    # entries are the executable cache's own counters
-    assert snap["histograms"] == {} and snap["gauges"] == {}
-    assert all(k.startswith("cache.") for k in snap["counters"])
-    assert obs.span_trees() == []
+    assert snap["counters"]["estimator.solves"] == 1
+    assert snap["gauges"] == {}
+    assert set(snap["histograms"]) == {
+        "cache.compile_seconds", "span.estimator.problem",
+        "span.estimator.solve", "span.estimator.prepare",
+        "span.estimator.compile"}
+    assert all(h["count"] == 1 for h in snap["histograms"].values())
 
 
 def test_ragged_solve_reports_padding_metrics():
@@ -310,3 +334,154 @@ def test_span_trees_nest_and_time():
     snap = obs.snapshot()
     assert snap["histograms"]["span.outer"]["count"] == 1
     assert snap["histograms"]["span.inner"]["count"] == 2
+
+
+def test_spans_record_nothing_while_obs_off():
+    """Disabled, the spans are bare profiler annotations: a solve leaves
+    the registry and the span ring empty."""
+    model, ts, y = _linear_problem()
+    est = Estimator(model, method="parallel_rts",
+                    options=ParallelOptions(nsub=NSUB))
+    for _ in range(2):                       # fresh, then cached
+        est.solve(Problem.single(model, ts, y))
+    with obs.trace_span("outer", solve_id=1):
+        with obs.trace_span("inner"):
+            pass
+    assert obs.REGISTRY.is_empty()
+    assert obs.span_trees() == []
+
+
+def _host_events(logdir):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    files = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                      recursive=True)
+    data = ProfileData.from_file(max(files, key=os.path.getmtime))
+    return [(e.name, dict(e.stats)) for p in data.planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events if e.name.startswith("estimator.")]
+
+
+# jaxlib's profile reader builds its stats type on first use, with a
+# DeprecationWarning that the suite's error filter would make fatal.
+@pytest.mark.filterwarnings("ignore:builtin type:DeprecationWarning")
+@pytest.mark.parametrize("enabled", [False, True], ids=["obs-off", "obs-on"])
+def test_profiler_trace_shows_solve_spans(tmp_path, enabled):
+    """The program's host spans land on the profiler's host plane whether
+    or not obs records them, and the spans of one solve share its id."""
+    if enabled:
+        obs.enable()
+    model, ts, y = _linear_problem()
+    est = Estimator(model, method="parallel_rts",
+                    options=ParallelOptions(nsub=NSUB))
+    est.solve(Problem.single(model, ts, y))        # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            jax.block_until_ready(est.solve(Problem.single(model, ts, y)).x)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    names = [n for n, _ in events]
+    for name in ("estimator.problem", "estimator.solve", "estimator.prepare",
+                 "estimator.dispatch"):
+        assert names.count(name) == 2, names
+    assert "estimator.compile" not in names
+    by_id = {}
+    for name, stats in events:
+        if name != "estimator.problem":
+            by_id.setdefault(stats["solve_id"], []).append(name)
+    assert len(by_id) == 2
+    for group in by_id.values():
+        assert sorted(group) == ["estimator.dispatch", "estimator.prepare",
+                                 "estimator.solve"]
+
+
+SCOPES = ("smoother.elements", "smoother.scan", "smoother.fill",
+          "smoother.recover", "estimator.cost", "nonlinear.linearize")
+
+
+@pytest.mark.parametrize("kind", ["linear", "two_filter", "nonlinear"])
+def test_named_scopes_in_the_lowered_program(kind):
+    """Each stage of a solve carries its named scope into the program's
+    debug info (the ops' ``op_name``, which a device profile shows)."""
+    if kind == "nonlinear":
+        model = coordinated_turn()
+        ts = time_grid(0.0, 1.0, 4 * NSUB)
+        _, y = simulate_nonlinear(model, ts, jax.random.PRNGKey(0))
+        est = Estimator(model, method="parallel_rts",
+                        options=IteratedOptions(
+                            inner=ParallelOptions(nsub=NSUB), iterations=2))
+        want = SCOPES
+    else:
+        model, ts, y = _linear_problem()
+        method = "parallel_rts" if kind == "linear" else "parallel_two_filter"
+        est = Estimator(model, method=method,
+                        options=get_method(method).options_cls(nsub=NSUB))
+        want = SCOPES[:-1]
+    text = est.lower(Problem.single(model, ts, y)).as_text(debug_info=True)
+    assert [s for s in want if s not in text] == []
+    if kind != "nonlinear":
+        assert "nonlinear.linearize" not in text
+
+
+def test_solve_neither_blocks_nor_reads_back(monkeypatch):
+    """With obs enabled, a cached solve dispatches and returns: nothing in
+    the call waits for the device or copies a result to the host."""
+    obs.enable()
+    model = coordinated_turn()
+    ts = time_grid(0.0, 1.0, 4 * NSUB)
+    _, y = simulate_nonlinear(model, ts, jax.random.PRNGKey(0))
+    est = Estimator(model, method="parallel_rts",
+                    options=IteratedOptions(
+                        inner=ParallelOptions(nsub=NSUB), iterations=2))
+    want = est.solve(Problem.single(model, ts, y))
+    jax.block_until_ready(want)
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("the solve waited for or read a device array")
+
+    array_type = type(want.x)
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    monkeypatch.setattr(array_type, "block_until_ready", refuse)
+    monkeypatch.setattr(array_type, "__array__", refuse)
+    monkeypatch.setattr(jax.Array, "__array__", refuse, raising=False)
+    sol = est.solve(Problem.single(model, ts, y))
+    monkeypatch.undo()
+    np.testing.assert_array_equal(np.asarray(sol.x), np.asarray(want.x))
+    np.testing.assert_array_equal(np.asarray(sol.cost_trace),
+                                  np.asarray(want.cost_trace))
+    assert obs.snapshot()["histograms"]["span.estimator.dispatch"][
+        "count"] == 1
+
+
+def test_fresh_solve_keys_its_cache_entry_by_metadata(monkeypatch):
+    """A fresh executable compiles with the module's metadata in its
+    persistent-cache key, so an entry of a build with other scopes is
+    never handed back for it; the flag is restored after the compile and
+    the next solve of the layout compiles nothing."""
+    from jax._src import compiler
+    from jax._src import config as jax_config
+
+    flag = jax_config.compilation_cache_include_metadata_in_key
+    seen = []
+    real = compiler.compile_or_get_cached
+
+    def spy(*args, **kwargs):
+        seen.append(flag.value)
+        return real(*args, **kwargs)
+
+    model, ts, y = _linear_problem(T=6 * NSUB)
+    problem = Problem.single(model, ts, y)
+    est = Estimator(model, method="parallel_rts",
+                    options=ParallelOptions(nsub=NSUB))
+    monkeypatch.setattr(compiler, "compile_or_get_cached", spy)
+    est.solve(problem)
+    compiled = len(seen)
+    assert compiled >= 1 and all(seen)
+    assert flag.value is False
+    est.solve(problem)
+    assert len(seen) == compiled

@@ -149,10 +149,40 @@ def euler_block_elements(grid: GridLQT, nsub: int,
 def discrete_block_elements(
     grid: GridLQT, nsub: int
 ) -> Tuple[LQTElement, LQTElement]:
-    """Exact composition mode: block elements by in-block combine scan.
+    """Exact composition mode: block elements by in-block combine fold.
 
     Returns ``(block_elems (T,...), substep_elems (T, n, ...))``.
+
+    In float32 with nx <= 8, a one-device program lowered for a TPU folds
+    the blocks in the Pallas kernel
+    :func:`repro.kernels.lqt_combine.ops.block_fold`, which keeps each
+    block's carry on chip for all n substeps.  The choice is made from the
+    lowering platform (``lax.platform_dependent``), so an ahead-of-time
+    compile for a TPU takes it too; every other program folds with a
+    ``lax.scan`` of :func:`lqt_combine` per block, vmapped over blocks.
     """
+    if (jnp.result_type(grid.dt, grid.F) != jnp.float32 or grid.nx > 8
+            or _several_devices()):
+        return _scan_block_elements(grid, nsub)
+    from repro.kernels.lqt_combine.ops import block_fold
+
+    return jax.lax.platform_dependent(
+        grid, tpu=lambda g: block_fold(g, nsub),
+        default=lambda g: _scan_block_elements(g, nsub))
+
+
+def _several_devices() -> bool:
+    """Whether a mesh of several devices is active (``mesh_context``, which
+    ``Estimator(mesh=...)`` and ``method="distributed"`` enter): XLA then
+    partitions the program, and it cannot partition a Mosaic kernel."""
+    from repro.distributed.sharding import active_mesh
+
+    mesh = active_mesh()
+    return mesh is not None and mesh.size > 1
+
+
+def _scan_block_elements(grid: GridLQT, nsub: int
+                         ) -> Tuple[LQTElement, LQTElement]:
     ones = one_step_elements(grid)
     T = grid.N // nsub
     sub = jax.tree_util.tree_map(

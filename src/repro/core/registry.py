@@ -16,6 +16,7 @@ canonical form and assigned :class:`~repro.core.options.ParallelOptions`.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, NamedTuple, Optional, Tuple, Type
 
 from .options import (
@@ -146,7 +147,8 @@ def _distributed_solver(grid: GridLQT, o: DistributedOptions) -> MAPSolution:
     degrades to the single-device parallel scan (``fallback="auto"``) or
     raises (``fallback="error"``).
     """
-    from repro.distributed.sharding import resolve_time_mesh
+    from repro.distributed.sharding import (
+        active_mesh, mesh_context, resolve_time_mesh)
 
     from . import pscan
     from .combine import affine_combine, lqt_combine
@@ -173,8 +175,12 @@ def _distributed_solver(grid: GridLQT, o: DistributedOptions) -> MAPSolution:
             affine_combine, elems, mesh=mesh, axis_name=o.time_axis,
             carry_dtype=carry_dtype)
 
-    return parallel_rts(grid, o.nsub, o.mode,
-                        suffix_scan_fn=suffix, prefix_scan_fn=prefix)
+    # The mesh becomes the ambient one (where none is), so the stages
+    # outside the sharded scans see that the program spans several devices.
+    with (mesh_context(mesh) if active_mesh() is None
+          else contextlib.nullcontext()):
+        return parallel_rts(grid, o.nsub, o.mode,
+                            suffix_scan_fn=suffix, prefix_scan_fn=prefix)
 
 
 register_method(

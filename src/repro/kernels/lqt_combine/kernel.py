@@ -1,23 +1,36 @@
-"""Pallas TPU kernel for the batched LQT combine (paper eq. 42).
+"""Pallas TPU kernels for the LQT combine (paper eq. 42).
 
 TPU adaptation (DESIGN.md S2): the elements are tiny (nx x nx with
-nx <= ~8) but the scan feeds the operator BATCHES of element pairs (one per
-tree node per level, times any outer batch).  A GPU implementation maps one
-element to one thread block; on TPU we instead put the BATCH in the 128-wide
-lane (minor) dimension and keep the matrix indices as tiny major dimensions:
+nx <= ~8) but the smoother combines BATCHES of them (one per tree node per
+scan level, one per block inside a block, times any outer batch).  A GPU
+implementation maps one element to one thread block; on TPU we instead put
+the BATCH on the vector unit's lanes and keep the matrix indices as tiny
+major dimensions, so that every entry (i, j) of a batch of elements is one
+array over the batch and every small-matrix op is an elementwise VPU op
+with static Python loops over i/j/k (nx is tiny and static).
+:func:`combine_entries` is that arithmetic, written once; two kernels run
+it:
 
-    layout (nx, nx, TB): element (i, j) entries of TB elements live in one
-    VREG row -> every small-matrix op becomes an elementwise VPU op over
-    lanes, with static Python loops over i/j/k (nx is tiny and static).
+* :func:`lqt_combine_lanes`, one combine of two element batches in the
+  layout (nx, nx, TB) / (nx, TB): entry (i, j) of TB elements is one VREG
+  row.  The scans of ``ops.py`` call it once per tree level.
+* :func:`block_fold_lanes`, the fold of each block's ``nsub`` substep
+  elements into the block element, in the layout (nsub, nx, nx, R, 128) /
+  (nsub, nx, R, 128): block ``t`` sits at row ``t // 128``, lane
+  ``t % 128``, so entry (i, j) of a tile of 8 x 128 blocks fills a whole
+  VREG.  Its grid runs over tiles of blocks and, innermost, over the
+  substeps; the carry stays in the output block in VMEM for all of them.
 
-The (I + C1 J2)^{-1} solve is an in-register Gauss-Jordan WITHOUT pivoting,
-which is safe here: C1, J2 are symmetric PSD, so C1 J2 has real nonnegative
-eigenvalues and every pivot of I + C1 J2 is >= 1 during elimination (the
-paper's invertibility argument, section 4.1).
-
-Block sizing: each grid step processes TB elements; all ten operand blocks
-plus temporaries fit comfortably in VMEM for TB = 512, nx <= 8
-(10 * nx^2 * TB * 4B ~ 1.3 MiB << 16 MiB VMEM).
+The (I + C1 J2)^{-1} inverse is an in-register Gauss-Jordan elimination
+WITHOUT pivoting (with pivoting by selects, the interpreter's compile at
+nx = 8 did not finish in ten minutes).  C1, J2 are symmetric PSD, so
+C1 J2 has real nonnegative eigenvalues and M is invertible (the paper's
+argument, section 4.1).  The pivots are ratios of M's leading principal
+minors: where J2 is diagonal (a measurement of state components, R
+diagonal) each minor is det(I + C1' J2') >= 1; where ||C1 J2|| < 1 none
+can vanish, and the pivots stay near 1, as in the block fold, whose J2 is
+a one-step dt H^T R^-1 H.  Otherwise a leading minor can vanish:
+C1 = [[1, 2], [2, 4]], J2 = [[1, -1], [-1, 1]] give M = [[0, 1], [-2, 3]].
 """
 from __future__ import annotations
 
@@ -28,88 +41,107 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# Sublane rows of 128 blocks in one grid step of the fold kernel: one
+# float32 VREG per matrix entry.
+FOLD_ROWS = 8
 
 
-def _matmat(X, Y, nx):
-    """(nx, nx, TB) @ (nx, nx, TB) -> (nx, nx, TB), lanes = batch."""
-    rows = []
-    for i in range(nx):
-        cols = []
-        for k in range(nx):
-            acc = X[i, 0] * Y[0, k]
-            for j in range(1, nx):
-                acc = acc + X[i, j] * Y[j, k]
-            cols.append(acc)
-        rows.append(jnp.stack(cols, axis=0))
-    return jnp.stack(rows, axis=0)
+def _dot(xs, ys):
+    acc = xs[0] * ys[0]
+    for x, y in zip(xs[1:], ys[1:]):
+        acc = acc + x * y
+    return acc
 
 
-def _matvec(X, v, nx):
-    """(nx, nx, TB) @ (nx, TB) -> (nx, TB)."""
-    rows = []
-    for i in range(nx):
-        acc = X[i, 0] * v[0]
-        for j in range(1, nx):
-            acc = acc + X[i, j] * v[j]
-        rows.append(acc)
-    return jnp.stack(rows, axis=0)
+def _mm(X, Y):
+    """Product of two matrices of entries (nested lists of batch arrays)."""
+    cols = _tr(Y)
+    return [[_dot(row, col) for col in cols] for row in X]
 
 
-def _transpose(X):
-    return jnp.swapaxes(X, 0, 1)
+def _mv(X, v):
+    return [_dot(row, v) for row in X]
 
 
-def _gauss_jordan_inverse(M, nx):
-    """Unpivoted Gauss-Jordan on (nx, nx, TB); rows are lane vectors."""
-    a = [[M[i, j] for j in range(nx)] for i in range(nx)]
-    inv = [[jnp.where(i == j, jnp.ones_like(M[0, 0]),
-                      jnp.zeros_like(M[0, 0]))
-            for j in range(nx)] for i in range(nx)]
-    for k in range(nx):
-        piv = 1.0 / a[k][k]
-        a[k] = [x * piv for x in a[k]]
-        inv[k] = [x * piv for x in inv[k]]
-        for i in range(nx):
-            if i == k:
-                continue
-            f = a[i][k]
-            a[i] = [x - f * y for x, y in zip(a[i], a[k])]
-            inv[i] = [x - f * y for x, y in zip(inv[i], inv[k])]
-    return jnp.stack([jnp.stack(r, axis=0) for r in inv], axis=0)
+def _tr(X):
+    return [list(col) for col in zip(*X)]
 
 
-def _combine_kernel(A1, b1, C1, e1, J1, A2, b2, C2, e2, J2,
-                    oA, ob, oC, oe, oJ, *, nx):
-    A1v, C1v, J2v, A2v, C2v, J1v = (
-        A1[...], C1[...], J2[...], A2[...], C2[...], J1[...])
-    b1v, e1v, b2v, e2v = b1[...], e1[...], b2[...], e2[...]
+def _add(x, y):
+    return [a + b for a, b in zip(x, y)]
 
-    # M = I + C1 J2; Minv once, M^-T via index transpose (free).
-    M = _matmat(C1v, J2v, nx)
-    eye_rows = []
-    for i in range(nx):
-        eye_rows.append(jnp.stack(
-            [M[i, j] + (1.0 if i == j else 0.0) for j in range(nx)], axis=0))
-    M = jnp.stack(eye_rows, axis=0)
-    Minv = _gauss_jordan_inverse(M, nx)
-    MinvT = _transpose(Minv)
 
-    MiA1 = _matmat(Minv, A1v, nx)
-    oA[...] = _matmat(A2v, MiA1, nx)
+def _sym_add(X, Y):
+    """``(X + Y + (X + Y)^T) / 2``, entrywise."""
+    n = len(X)
+    S = [_add(X[i], Y[i]) for i in range(n)]
+    return [[0.5 * (S[i][j] + S[j][i]) for j in range(n)] for i in range(n)]
 
-    tmp = b1v + _matvec(C1v, e2v, nx)
-    ob[...] = _matvec(A2v, _matvec(Minv, tmp, nx), nx) + b2v
 
-    MiC1 = _matmat(Minv, C1v, nx)
-    C12 = _matmat(A2v, _matmat(MiC1, _transpose(A2v), nx), nx) + C2v
-    oC[...] = 0.5 * (C12 + _transpose(C12))
+def _inverse(M):
+    """Gauss-Jordan inverse without pivoting of a matrix of entries."""
+    n = len(M)
+    one, zero = jnp.ones_like(M[0][0]), jnp.zeros_like(M[0][0])
+    rows = [list(M[i]) + [one if j == i else zero for j in range(n)]
+            for i in range(n)]
+    for k in range(n):
+        piv = 1.0 / rows[k][k]
+        rows[k] = [x * piv for x in rows[k]]
+        for i in range(n):
+            if i != k:
+                f = rows[i][k]
+                rows[i] = [x - f * y for x, y in zip(rows[i], rows[k])]
+    return [row[n:] for row in rows]
 
-    w = e2v - _matvec(J2v, b1v, nx)
-    oe[...] = _matvec(_transpose(A1v), _matvec(MinvT, w, nx), nx) + e1v
 
-    MtJ2 = _matmat(MinvT, J2v, nx)
-    J12 = _matmat(_transpose(A1v), _matmat(MtJ2, A1v, nx), nx) + J1v
-    oJ[...] = 0.5 * (J12 + _transpose(J12))
+def combine_entries(e1, e2):
+    """Eq. (42), ``e1`` on the earlier interval, on elements held as
+    entries: ``(A, b, C, eta, J)`` with ``A``, ``C``, ``J`` nested lists of
+    ``nx x nx`` arrays and ``b``, ``eta`` lists of ``nx``, every entry an
+    array over the batch (of any one shape)."""
+    A1, b1, C1, eta1, J1 = e1
+    A2, b2, C2, eta2, J2 = e2
+    n = len(A1)
+    # M = I + C1 J2, inverted once; M^-T by index transpose (free).
+    M = _mm(C1, J2)
+    M = [[M[i][j] + 1.0 if i == j else M[i][j] for j in range(n)]
+         for i in range(n)]
+    Minv = _inverse(M)
+    MinvT, A1T = _tr(Minv), _tr(A1)
+    A = _mm(A2, _mm(Minv, A1))
+    b = _add(_mv(A2, _mv(Minv, _add(b1, _mv(C1, eta2)))), b2)
+    C = _sym_add(_mm(A2, _mm(_mm(Minv, C1), _tr(A2))), C2)
+    w = [x - y for x, y in zip(eta2, _mv(J2, b1))]
+    eta = _add(_mv(A1T, _mv(MinvT, w)), eta1)
+    J = _sym_add(_mm(A1T, _mm(_mm(MinvT, J2), A1)), J1)
+    return A, b, C, eta, J
+
+
+def _entries(e, nx):
+    """Entries of an element ``(A, b, C, eta, J)`` of arrays or refs whose
+    two (matrix) or one (vector) leading axes are the indices."""
+    A, b, C, eta, J = e
+
+    def mat(X):
+        return [[X[i, j] for j in range(nx)] for i in range(nx)]
+
+    def vec(v):
+        return [v[i] for i in range(nx)]
+
+    return mat(A), vec(b), mat(C), vec(eta), mat(J)
+
+
+def _combine_kernel(*refs, nx):
+    e1, e2 = (_entries(tuple(r[...] for r in refs[s:s + 5]), nx)
+              for s in (0, 5))
+    A, b, C, eta, J = combine_entries(e1, e2)
+    oA, ob, oC, oe, oJ = refs[10:]
+
+    def stack(X):
+        return jnp.stack([jnp.stack(row, axis=0) for row in X], axis=0)
+
+    oA[...], oC[...], oJ[...] = stack(A), stack(C), stack(J)
+    ob[...], oe[...] = jnp.stack(b, axis=0), jnp.stack(eta, axis=0)
 
 
 def lqt_combine_lanes(ops1, ops2, *, block_b: int = 512,
@@ -118,6 +150,8 @@ def lqt_combine_lanes(ops1, ops2, *, block_b: int = 512,
 
     ``ops1``/``ops2``: tuples (A, b, C, eta, J) with shapes
     (nx, nx, B) / (nx, B); B must be a multiple of ``block_b``.
+    All ten operand blocks plus temporaries fit in VMEM for
+    ``block_b = 512``, nx <= 8 (10 * nx^2 * TB * 4 B ~ 1.3 MiB).
     """
     A1, b1, C1, e1, J1 = ops1
     A2, b2, C2, e2, J2 = ops2
@@ -146,3 +180,60 @@ def lqt_combine_lanes(ops1, ops2, *, block_b: int = 512,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(A1, b1, C1, e1, J1, A2, b2, C2, e2, J2)
+
+
+def _fold_kernel(A, b, C, eta, J, oA, ob, oC, oeta, oJ, *, nx):
+    """Grid step (tile i, substep k): out <- e_k at k = 0, else
+    out <- combine(out, e_k).  The output block is the same for every k,
+    so the carry stays in VMEM until the tile is done."""
+    step, out = (A, b, C, eta, J), (oA, ob, oC, oeta, oJ)
+
+    def store(e):
+        for ref, x in zip(out, e):
+            for i in range(nx):
+                if isinstance(x[i], list):
+                    for j in range(nx):
+                        ref[i, j] = x[i][j]
+                else:
+                    ref[i] = x[i]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        store(_entries(step, nx))
+
+    @pl.when(pl.program_id(1) > 0)
+    def _fold():
+        store(combine_entries(_entries(out, nx), _entries(step, nx)))
+
+
+def block_fold_lanes(ops, *, rows: int = FOLD_ROWS,
+                     interpret: bool = False):
+    """Fold each block's substep elements left to right with eq. (42).
+
+    ``ops``: (A, b, C, eta, J) with shapes (nsub, nx, nx, R, 128) /
+    (nsub, nx, R, 128), block ``t`` at ``[..., t // 128, t % 128]``;
+    R must be a multiple of ``rows``.  Returns the block elements,
+    (nx, nx, R, 128) / (nx, R, 128):
+    ``e_0 (x) e_1 (x) ... (x) e_{nsub-1}`` per block.
+    """
+    A = ops[0]
+    nsub, nx, _, R, lanes = A.shape
+    assert R % rows == 0 and lanes == 128, (A.shape, rows)
+    mat_in = pl.BlockSpec((None, nx, nx, rows, 128),
+                          lambda i, k: (k, 0, 0, i, 0))
+    vec_in = pl.BlockSpec((None, nx, rows, 128), lambda i, k: (k, 0, i, 0))
+    mat_out = pl.BlockSpec((nx, nx, rows, 128), lambda i, k: (0, 0, i, 0))
+    vec_out = pl.BlockSpec((nx, rows, 128), lambda i, k: (0, i, 0))
+    mat = jax.ShapeDtypeStruct((nx, nx, R, 128), A.dtype)
+    vec = jax.ShapeDtypeStruct((nx, R, 128), A.dtype)
+    return pl.pallas_call(
+        functools.partial(_fold_kernel, nx=nx),
+        grid=(R // rows, nsub),
+        in_specs=[mat_in, vec_in, mat_in, vec_in, mat_in],
+        out_specs=(mat_out, vec_out, mat_out, vec_out, mat_out),
+        out_shape=(mat, vec, mat, vec, mat),
+        # tiles are independent; the substeps of a tile fold in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*ops)

@@ -9,7 +9,7 @@ ONE ``_to_lanes``/``_from_lanes`` round-trip total, with every scan level
 slicing/combining lane-major operands in place.  The multi-level tree is
 the same work-efficient recursion as ``jax.lax.associative_scan``, so the
 combine ORDER matches the jnp scan; the per-combine arithmetic still
-differs (unpivoted Gauss-Jordan vs pivoted ``linalg.solve``), so results
+differs (an inverse by Gauss-Jordan vs ``linalg.solve``), so results
 agree to tolerance, not bit-exactly.
 
 On the CPU backend ``interpret=True`` executes the kernel body with the
@@ -19,14 +19,17 @@ tests run it; on a TPU the kernel compiles with Mosaic.
 from __future__ import annotations
 
 import functools
+import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core.types import LQTElement
+from repro.core.elements import one_step_elements
+from repro.core.types import GridLQT, LQTElement
 
-from .kernel import lqt_combine_lanes
+from .kernel import FOLD_ROWS, block_fold_lanes, lqt_combine_lanes
 
 
 def _to_lanes(e: LQTElement):
@@ -61,7 +64,7 @@ def _combine_lanes(ops1, ops2, *, block_b: int, interpret: bool):
     """Kernel combine on lane-major 5-tuples of ANY lane count.
 
     Pads both operand tuples to a ``block_b`` multiple (zero lanes are
-    garbage-free: C1 J2 = 0 so the Gauss-Jordan pivots stay 1) and slices
+    garbage-free: C1 J2 = 0, so the Gauss-Jordan sees M = I) and slices
     the pad back off.  ``B == 0`` (empty tree levels) short-circuits.
 
     Obs: each call increments the ``kernel.lqt_combine.*`` launch
@@ -93,6 +96,81 @@ def lqt_combine_batched(e1: LQTElement, e2: LQTElement, *,
         return e1
     return _from_lanes(_combine_lanes(_to_lanes(e1), _to_lanes(e2),
                                       block_b=block_b, interpret=interpret))
+
+
+# ---------------------------------------------------------------------------
+# In-block fold: each block's substep elements into its block element
+# ---------------------------------------------------------------------------
+
+
+def block_fold(grid: GridLQT, nsub: int, *, interpret: bool = False
+               ) -> Tuple[LQTElement, LQTElement]:
+    """Kernel-backed ``discrete``-mode elements:
+    ``(block_elems (T, ...), substep_elems (T, nsub, ...))`` as
+    :func:`repro.core.elements.discrete_block_elements` returns them, each
+    block element the fold ``e_0 (x) ... (x) e_{nsub-1}`` of its block's
+    one-step elements.
+
+    The grid's samples are reordered substep-major first
+    (:func:`_substep_lanes`), so the one-step elements come out in the
+    kernel's order, (nsub, nx, nx, R, 128) with block ``t`` at row
+    ``t // 128``, lane ``t % 128``; nothing is laid out with the substep
+    index minor, which XLA pads from nsub to 128 lanes.  ``T`` is padded
+    to a whole number of kernel tiles (up to 8 rows, else a multiple of 8)
+    with ``dt = 0`` and zero samples, whose one-step elements are identity
+    elements, so pad lanes stay finite.  Obs: the trace-time counters
+    ``kernel.block_fold.lanes`` / ``.pad_lanes`` count the blocks folded
+    and the pad lanes per kernel call site traced.
+    """
+    T = grid.N // nsub
+    rows = -(-T // 128)
+    tile = min(rows, FOLD_ROWS)
+    rows = -(-rows // tile) * tile
+    if obs.enabled():
+        obs.inc("kernel.block_fold.lanes", T)
+        obs.inc("kernel.block_fold.pad_lanes", rows * 128 - T)
+
+    names = ("dt", "F", "c", "H", "r", "Q", "Rinv", "y", "lin")
+    fields = {f: getattr(grid, f) for f in names
+              if getattr(grid, f) is not None}
+    lanes = _substep_lanes(jnp.concatenate(
+        [a.reshape(grid.N, -1) for a in fields.values()], axis=1), nsub, rows)
+    start = 0
+    for f, a in fields.items():       # substep-major samples, (N', ...)
+        width = math.prod(a.shape[1:])
+        part = lanes[:, start:start + width].reshape(
+            (nsub,) + a.shape[1:] + (rows * 128,))
+        fields[f] = jnp.moveaxis(part, -1, 1).reshape((-1,) + a.shape[1:])
+        start += width
+    ones = one_step_elements(grid._replace(**fields))
+
+    def to_lanes(a):       # (nsub * rows * 128, ...) -> (nsub, ..., rows, 128)
+        a = jnp.moveaxis(a.reshape((nsub, rows * 128) + a.shape[1:]), 1, -1)
+        return a.reshape(a.shape[:-1] + (rows, 128))
+
+    ones = tuple(map(to_lanes, ones))
+
+    def from_lanes(a):     # (..., rows, 128) -> (T, ...)
+        a = jnp.moveaxis(a, (-2, -1), (0, 1))
+        return a.reshape((rows * 128,) + a.shape[2:])[:T]
+
+    blocks = block_fold_lanes(ones, rows=tile, interpret=interpret)
+    sub = [from_lanes(a) for a in ones]
+    return (LQTElement(*map(from_lanes, blocks)), LQTElement(*sub))
+
+
+def _substep_lanes(x, nsub: int, rows: int):
+    """``(T * nsub, W)`` samples -> ``(nsub, W, rows, 128)``: substep ``k``
+    of block ``t`` at ``[k, :, t // 128, t % 128]``, zero past block ``T``.
+
+    A ``(W, rows, 128 * nsub)`` view takes one strided lane slice per
+    substep; XLA lays it out with the long axis minor throughout, where a
+    ``(T, nsub)`` view would be padded from nsub to 128 lanes.
+    """
+    T = x.shape[0] // nsub
+    x = jnp.pad(x.T, ((0, 0), (0, (rows * 128 - T) * nsub)))
+    x = x.reshape(x.shape[0], rows, 128 * nsub)
+    return jnp.stack([x[..., k::nsub] for k in range(nsub)])
 
 
 # ---------------------------------------------------------------------------

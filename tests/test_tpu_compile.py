@@ -100,19 +100,72 @@ def test_parallel_kernel_solve_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_parallel_rts_long_horizon_compiles(one_chip):
+@pytest.fixture(scope="module")
+def parallel_rts_10240(one_chip):
+    with jax.enable_x64(False):
+        N = 10_240
+        fn = _solve_fn("parallel_rts",
+                       ParallelOptions(nsub=10, mode="discrete"))
+        return _compile(fn, _spec((N + 1,), one_chip),
+                        _spec((N, 2), one_chip))
+
+
+def _time_batched_convolutions(text, N):
+    conv_shapes = re.findall(r"= f32\[([\d,]*)\]\S* convolution\(", text)
+    return [s for s in conv_shapes
+            if any(int(d) >= N // 10 for d in s.split(",") if d)]
+
+
+def test_parallel_rts_long_horizon_compiles(parallel_rts_10240):
     """The plain-XLA smoother compiles well inside two minutes at a horizon
     where the batched dots and pivoted LU of ``jnp`` took minutes and grew
     with N (core/linalg.py)."""
-    N = 10_240
-    fn = _solve_fn("parallel_rts", ParallelOptions(nsub=10, mode="discrete"))
-    compiled, seconds = _compile(fn, _spec((N + 1,), one_chip),
-                                 _spec((N, 2), one_chip))
+    compiled, seconds = parallel_rts_10240
     # No dot batched over the time axis: the TPU emits those as
     # convolutions over the batch.  Single small dots may remain.
-    conv_shapes = re.findall(r"= f32\[([\d,]*)\]\S* convolution\(",
-                             compiled.as_text())
-    assert not [s for s in conv_shapes
-                if any(int(d) >= N // 10 for d in s.split(",") if d)]
+    assert not _time_batched_convolutions(compiled.as_text(), 10_240)
     assert seconds < 120, seconds
     assert np.isfinite(compiled.memory_analysis().temp_size_in_bytes)
+
+
+def test_parallel_rts_folds_blocks_in_the_kernel(parallel_rts_10240):
+    """On a TPU the in-block fold of ``smoother.elements`` is one Pallas
+    kernel: a ``tpu_custom_call`` in that scope, which the benchmark's
+    ``block_ms`` reads, and no ``while`` over the substeps left in it."""
+    text = parallel_rts_10240[0].as_text()
+    in_scope = [line for line in text.splitlines()
+                if "smoother.elements" in line and " = " in line]
+    assert any('custom_call_target="tpu_custom_call"' in line
+               for line in in_scope)
+    assert not [line for line in in_scope if re.search(r" while\(", line)]
+    assert not _time_batched_convolutions(text, 10_240)
+
+
+def test_stacked_solve_folds_blocks_in_the_kernel(one_chip):
+    """Stacked records, as the engines solve them: the kernel batches."""
+    B, N = 8, 2_560
+    fn = jax.vmap(_solve_fn("parallel_rts",
+                            ParallelOptions(nsub=10, mode="discrete")))
+    compiled, _ = _compile(fn, _spec((B, N + 1), one_chip),
+                           _spec((B, N, 2), one_chip))
+    assert any("tpu_custom_call" in line and "smoother.elements" in line
+               for line in compiled.as_text().splitlines())
+
+
+def test_distributed_solve_compiles_on_four_chips(topo):
+    """XLA cannot partition a Mosaic kernel, so a solve over a mesh of
+    several chips keeps the XLA fold."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core.options import DistributedOptions
+    from repro.distributed.sharding import mesh_context
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("time",))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    N = 2_560
+    with mesh_context(mesh):
+        compiled, _ = _compile(
+            _solve_fn("distributed", DistributedOptions(nsub=10,
+                                                        mode="discrete")),
+            _spec((N + 1,), replicated), _spec((N, 2), replicated))
+    assert "tpu_custom_call" not in compiled.as_text()

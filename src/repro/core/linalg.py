@@ -1,10 +1,9 @@
 """Batched products and solves of small dense matrices.
 
 The smoothers multiply and solve one ``nx x nx`` system per grid point or
-per scan element, with ``nx`` of 2-8 and up to ~1e6 points.  Written as
-``@``/``jnp.linalg.solve``, these lower to XLA dots and pivoted LU
-decompositions batched over the time axis, which the TPU compiler handles
-badly at long horizons:
+per scan element, at up to ~1e6 points.  Written as ``@``/``jnp.linalg.solve``,
+these lower to XLA dots and pivoted LU decompositions batched over the time
+axis, which the TPU compiler handles badly at long horizons:
 
 * a batched dot becomes a convolution whose compile cost grows with the
   batch, and where its operands are the model's constant coefficients
@@ -12,20 +11,24 @@ badly at long horizons:
   ``(N, 2, 4) x (N, 2, 2)`` product took 7.6 s to compile for v5e at
   N = 2 560 and 112 s at N = 20 480;
 * the gather that applies LU's pivot permutation compiles in about 75 s for
-  a ``(1e5, 4, 4)`` batch, where the elimination below compiles in about
-  2 s;
+  a ``(1e5, 4, 4)`` batch, where the elimination below compiles in about 2 s;
 * a float32 dot on the TPU runs at the MXU's default (bfloat16-pass)
   precision, while the products below are exact float32 arithmetic.
 
-So in float32, the chip's dtype, every function here is built from
-broadcasts, multiplies, selects and reductions over the small trailing
-axes, which XLA fuses.  In float64, which only the CPU runs (the tests, with
-x64 on), they are ``jnp``'s dots and LAPACK calls.  One form for both
-dtypes was tried and measured on the CPU:
+So in float32, the chip's dtype, every function here is built from broadcasts,
+multiplies, selects and reductions over the small trailing axes, which XLA
+fuses.  The solve is a rolled loop over the ``n`` steps of the elimination,
+each a fixed few ops on the augmented matrix, so its traced size does not grow
+with ``n``: a float32 ``parallel_rts`` solve at N = 400 lowers on the CPU to
+4 264 StableHLO lines at nx 10 and 40 alike, against 22 233 and 605 037 (134 s)
+with a loop over rows inside each step, and 8 812 and 27 682 with the steps
+unrolled (a five-pass 40-wide solve at N = 5e4 then compiled for v5e in 2 379 s,
+against 25-35 s rolled).  In float64, which only the CPU runs (the tests, with
+x64 on), they are ``jnp``'s dots and LAPACK calls.  One form for both dtypes
+was tried and measured on the CPU:
 
 * the elementwise forms in float64 made XLA:CPU's compiles about three
-  times slower (the unrolled elimination) and the full test suite 1.7
-  times slower;
+  times slower (the elimination) and the full test suite 1.7 times slower;
 * elementwise products with LAPACK solves gave a wrong trajectory for one
   record of a time-sharded stacked solve, right again with XLA:CPU's
   concurrency-optimized scheduler turned off (PERF.md, open questions).
@@ -39,6 +42,7 @@ leading batch axes, like ``jnp.linalg``.
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 
 def _f64(*args) -> bool:
@@ -76,35 +80,30 @@ def solve(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
         return jnp.linalg.solve(A, B)
     n = A.shape[-1]
     batch = jnp.broadcast_shapes(A.shape[:-2], B.shape[:-2])
-    dtype = jnp.result_type(A, B)
-    A = jnp.broadcast_to(A, batch + A.shape[-2:]).astype(dtype)
-    B = jnp.broadcast_to(B, batch + B.shape[-2:]).astype(dtype)
-    rows = [jnp.concatenate([A[..., i, :], B[..., i, :]], axis=-1)
-            for i in range(n)]
-    for k in range(n):
-        # Pivot: the row at or below k with the largest |entry| in column k.
-        piv, best = rows[k], jnp.abs(rows[k][..., k])
-        which = [None] * n
-        for i in range(k + 1, n):
-            cand = jnp.abs(rows[i][..., k])
-            which[i] = cand > best
-            # A later strictly larger candidate supersedes earlier picks.
-            for j in range(k + 1, i):
-                which[j] = which[j] & ~which[i]
-            piv = jnp.where(which[i][..., None], rows[i], piv)
-            best = jnp.maximum(cand, best)
-        for i in range(k + 1, n):
-            rows[i] = jnp.where(which[i][..., None], rows[k], rows[i])
-        piv = piv / piv[..., k:k + 1]
-        rows = [piv if i == k else rows[i] - rows[i][..., k:k + 1] * piv
-                for i in range(n)]
-    return jnp.stack([r[..., n:] for r in rows], axis=-2)
+    M = jnp.concatenate([jnp.broadcast_to(A, batch + A.shape[-2:]),
+                         jnp.broadcast_to(B, batch + B.shape[-2:])],
+                        axis=-1).astype(jnp.result_type(A, B))
+    row = jnp.arange(n)[:, None]
+
+    def step(k, M):
+        # Pivot: the first row i >= k with the largest |M[..., i, k]|.  Rows
+        # are read by one-hot sums and exchanged by selects, not gathers.
+        col = lax.dynamic_index_in_dim(M, k, axis=-1, keepdims=False)
+        p = jnp.argmax(jnp.where(row[:, 0] >= k, jnp.abs(col), -1.0), axis=-1)
+        at_p = row == p[..., None, None]
+        piv = jnp.sum(jnp.where(at_p, M, 0.0), axis=-2, keepdims=True)
+        rk = jnp.sum(jnp.where(row == k, M, 0.0), axis=-2)
+        u = piv / lax.dynamic_index_in_dim(piv, k, axis=-1)
+        c = jnp.where(at_p[..., 0], lax.dynamic_index_in_dim(rk, k, -1), col)
+        S = jnp.where(at_p, rk[..., None, :], M)
+        return jnp.where(row == k, u, S - c[..., None] * u)
+
+    return lax.fori_loop(0, n, step, M)[..., n:]
 
 
 def inv(A: jnp.ndarray) -> jnp.ndarray:
     """``A^{-1}`` for ``A`` ``(..., n, n)``."""
     if _f64(A):
         return jnp.linalg.inv(A)
-    n = A.shape[-1]
-    return solve(A, jnp.broadcast_to(jnp.eye(n, dtype=A.dtype), A.shape))
+    return solve(A, jnp.eye(A.shape[-1], dtype=A.dtype))
 

@@ -145,6 +145,13 @@ def time_grid(t0: float, tf: float, num_steps: int, dtype=jnp.float64) -> Array:
     return jnp.linspace(t0, tf, num_steps + 1, dtype=dtype)
 
 
+def _noise_cov(cov, ts: Array) -> Array:
+    """A noise covariance at each of ``ts`` if it is a function of time, else
+    the one matrix: products broadcast it, and it is inverted once, not at
+    every point."""
+    return jax.vmap(cov)(ts) if callable(cov) else jnp.asarray(cov)
+
+
 def build_grid_lqt(
     F: Array, c: Array, H: Array, r: Array, Q: Array, R: Array,
     y: Array, dt: Array, m0: Array, P0: Array,
@@ -155,7 +162,8 @@ def build_grid_lqt(
     """Time-reverse grid coefficients into the LQT problem of section 2.4.
 
     Reversed interval ``j`` <- original interval ``N-1-j``;
-    ``F~ = -F``, ``c~ = -c`` (section 2.2 definitions).
+    ``F~ = -F``, ``c~ = -c`` (section 2.2 definitions).  ``R`` is one
+    ``(ny, ny)`` matrix or one per interval.
 
     ``measurement_mask`` (``(N,)``, original time order, 1.0 = real) zeroes
     ``R^{-1}`` (and the optional linear cost) on masked intervals, removing
@@ -172,7 +180,7 @@ def build_grid_lqt(
     MAP restricted to the window (docs/STREAMING.md).
     """
     flip = lambda a: jnp.flip(a, axis=0)
-    Rinv = linalg.inv(R)
+    Rinv = jnp.broadcast_to(linalg.inv(R), y.shape[:1] + R.shape[-2:])
     if measurement_mask is not None:
         Rinv = Rinv * measurement_mask[:, None, None]
         if lin is not None:
@@ -197,7 +205,8 @@ def grid_lqt_from_linear(
     measurement_mask: Optional[Array] = None,
     prior: Optional[Prior] = None,
 ) -> GridLQT:
-    F, c, H, r, Q, R = model.grids(ts)
+    F, c, H, r, Q, _ = model.grids(ts)
+    R = _noise_cov(model.R, ts[:-1])
     dt = jnp.diff(ts)
     return build_grid_lqt(F, c, H, r, Q, R, y, dt, model.m0, model.P0,
                           measurement_mask=measurement_mask, prior=prior)
@@ -226,7 +235,7 @@ def grid_lqt_from_nonlinear(
     lin_strategy = get_linearization(linearization)
     tl = ts[:-1]
     Q = model._eval(model.Q, tl)
-    R = model._eval(model.R, tl)
+    R = _noise_cov(model.R, tl)
     if not lin_strategy.has_residual:
         F, c, H, r = model.linearise(xbar, ts)
     else:
@@ -361,8 +370,8 @@ def om_cost_nonlinear(
 ) -> Array:
     dt = jnp.diff(ts)
     tl = ts[:-1]
-    Q = model._eval(model.Q, tl)
-    R = model._eval(model.R, tl)
+    Q = _noise_cov(model.Q, tl)
+    R = _noise_cov(model.R, tl)
     cost = _prior_cost(model, x[0], prior)
     xr = x[1:]
     fx = jax.vmap(model.f)(xr, tl)

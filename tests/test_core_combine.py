@@ -1,5 +1,6 @@
 """Property tests for the associative operators (paper eqs. 29/42, 45-46)."""
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -168,7 +169,7 @@ def test_combine_psd_preserved(seed):
 
 @pytest.mark.parametrize("n", [1, 2, 4, 5, 8])
 def test_small_linalg_float32_forms_match_numpy(n):
-    """``core.linalg`` (elementwise products, the unrolled pivoting
+    """``core.linalg`` (elementwise products, the pivoting
     Gauss-Jordan solve) in float32, the chip's dtype, against float64
     numpy."""
     _check_small_linalg(n, jnp.float32, tol=1e-4)
@@ -205,3 +206,109 @@ def _check_small_linalg(n, dtype, tol):
                                np.linalg.solve(A[1], B), **tol)
     np.testing.assert_allclose(linalg.inv(cast(A)), np.linalg.inv(A), **tol)
     assert linalg.solve(cast(A), cast(B)).dtype == dtype
+
+
+def _systems(n, kind, batch=8):
+    """Seeded float32 systems ``(A, B)``.  ``zero_lead``: a zero in the
+    leading entry, so the first step must exchange rows.  ``tied``: random
+    sign matrices, whose columns hold equal magnitudes at most steps of the
+    elimination, so the rule for ties decides the pivot.  Draws with a
+    condition number of 1e4 or more are drawn again."""
+    rng = np.random.default_rng([n, len(kind)])
+    As = []
+    while len(As) < batch:
+        if kind == "tied":
+            A = rng.choice([-1.0, 1.0], size=(n, n))
+        else:
+            A = rng.standard_normal((n, n))
+            if kind == "zero_lead":
+                A[0, 0] = 0.0
+        if np.linalg.cond(A) < 1e4:
+            As.append(A)
+    B = rng.standard_normal((batch, n, 3))
+    return np.float32(As), np.float32(B)
+
+
+def _gauss_jordan_f32(A, B, last_of_ties=False):
+    """Plain numpy Gauss-Jordan in float32 with partial pivoting, a tie
+    going to the first of equal maxima (or the last): ``A^{-1} B`` and the
+    pivot row of each step."""
+    n = A.shape[0]
+    M = np.concatenate([A, B], axis=-1).astype(np.float32)
+    pivots = []
+    for k in range(n):
+        col = np.abs(M[k:, k])
+        p = k + (len(col) - 1 - int(np.argmax(col[::-1])) if last_of_ties
+                 else int(np.argmax(col)))
+        pivots.append(p)
+        M[[k, p]] = M[[p, k]]
+        M[k] = M[k] / M[k, k]
+        for i in range(n):
+            if i != k:
+                M[i] = M[i] - M[i, k] * M[k]
+    return M[:, n:], pivots
+
+
+@pytest.mark.parametrize("kind", ["random", "zero_lead", "tied"])
+@pytest.mark.parametrize("n", [2, 4, 5, 8, 16, 40])
+def test_float32_solve_and_inv_match_float64(n, kind):
+    """The float32 solve and inverse against float64 ``jnp.linalg``, with
+    an error bound scaled by each system's condition number: partial
+    pivoting is backward stable, so the forward error is at most about
+    ``cond(A) n eps``."""
+    from repro.core import linalg
+
+    A, B = _systems(n, kind)
+    pairs = [(jax.jit(linalg.solve)(A, B),
+              jnp.linalg.solve(np.float64(A), np.float64(B))),
+             (jax.jit(linalg.inv)(A), jnp.linalg.inv(np.float64(A)))]
+    eps = np.finfo(np.float32).eps
+    for X, X64 in pairs:
+        assert X.dtype == np.float32
+        for a, x, x64 in zip(A, np.asarray(X), np.asarray(X64)):
+            err = np.max(np.abs(x - x64)) / np.max(np.abs(x64))
+            assert err < 4 * n * eps * np.linalg.cond(np.float64(a)), err
+
+
+@pytest.mark.parametrize("kind", ["random", "zero_lead", "tied"])
+@pytest.mark.parametrize("n", [4, 5, 8])
+def test_float32_solve_takes_numpy_pivots(n, kind):
+    """Run op by op with jit off (no fusion, so no contraction or
+    reordering of the arithmetic), the float32 solve equals a plain numpy
+    float32 Gauss-Jordan bit for bit: it takes the same pivot at every
+    step, the first of equal maxima on a tie."""
+    from repro.core import linalg
+
+    A, B = _systems(n, kind, batch=4)
+    with jax.disable_jit():
+        X = np.asarray(linalg.solve(A, B))
+    decided_by_tie = 0
+    for a, b, x in zip(A, B, X):
+        ref, pivots = _gauss_jordan_f32(a, b)
+        np.testing.assert_array_equal(x, ref)
+        decided_by_tie += pivots != _gauss_jordan_f32(a, b, True)[1]
+    assert decided_by_tie or kind != "tied"
+
+
+def _count_eqns(jaxpr):
+    """Equations of a jaxpr, those of its sub-jaxprs included."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if isinstance(sub, jax.extend.core.Jaxpr):
+                total += _count_eqns(sub)
+    return total
+
+
+def test_float32_solve_traced_size_is_linear_in_n():
+    """A fixed number of operations per elimination step: the traced size
+    of a 40 x 40 solve stays under a bound linear in n (a form with a
+    loop over rows inside the loop over steps traces about n^3 / 6)."""
+    from repro.core import linalg
+
+    n = 40
+    jaxpr = jax.make_jaxpr(linalg.solve)(np.zeros((n, n), np.float32),
+                                         np.zeros((n, 3), np.float32))
+    assert _count_eqns(jaxpr.jaxpr) <= 40 * n

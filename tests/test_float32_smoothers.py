@@ -1,7 +1,7 @@
 """Every smoother in float32, the chip's dtype, against float64.
 
 The rest of the suite runs with x64 on, where ``core.linalg`` solves by
-LAPACK; in float32 it solves by the unrolled Gauss-Jordan elimination the
+LAPACK; in float32 it solves by the pivoting Gauss-Jordan elimination the
 chip runs.  These tests run that form end to end on the CPU, through
 ``Estimator.solve``, and compare each trajectory with a float64 one:
 the numpy Kalman/RTS smoother for the linear model, the library's own

@@ -1,4 +1,6 @@
 """Iterated (parallel) MAP estimation on the coordinated-turn model (5.2)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,12 +13,14 @@ from repro.core import (
     Problem,
     SequentialOptions,
     TwoFilterOptions,
+    grid_lqt_from_linear,
+    grid_lqt_from_nonlinear,
     om_cost_nonlinear,
     simulate_nonlinear,
     time_grid,
 )
 
-from helpers import coordinated_turn
+from helpers import coordinated_turn, wiener_velocity
 
 
 @pytest.fixture(scope="module")
@@ -136,3 +140,25 @@ def test_x_init_warm_start(ct_problem):
         Problem.single(model, ts, y, x_init=model.m0))
     cold = _ieks(model, "parallel_rts", inner, iterations=1).solve(problem)
     np.testing.assert_allclose(point.x, cold.x, rtol=1e-9, atol=1e-9)
+
+
+@pytest.mark.parametrize("model_name, noise", [("ct", "Q"), ("ct", "R"),
+                                               ("wv", "R")])
+def test_constant_noise_matches_it_as_a_function_of_time(ct_problem,
+                                                         model_name, noise):
+    """A constant noise covariance is inverted once and broadcast along the
+    grid: the grid problem and the OM cost equal those of the same
+    covariance given as a function of time, evaluated and inverted at
+    every point."""
+    _, ts, xs, y = ct_problem
+    model = coordinated_turn() if model_name == "ct" else wiener_velocity()
+    value = getattr(model, noise)
+    timed = dataclasses.replace(model, **{noise: lambda t: value})
+    if model_name == "ct":
+        pairs = [(grid_lqt_from_nonlinear(m, ts, y, xs),
+                  om_cost_nonlinear(m, ts, y, xs)) for m in (model, timed)]
+    else:
+        pairs = [(grid_lqt_from_linear(m, ts, y), ()) for m in (model, timed)]
+    for a, b in zip(jax.tree.leaves(pairs[0]), jax.tree.leaves(pairs[1])):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-12, atol=0)
